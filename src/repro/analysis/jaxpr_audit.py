@@ -44,6 +44,7 @@ from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 from jax._src import source_info_util
 
@@ -59,7 +60,8 @@ AUDIT_RULES: Dict[str, str] = {
 
 # primitives that move data to/from the host or another device placement
 TRANSFER_PRIMS = {
-    "pure_callback", "io_callback", "debug_callback", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback",
     "device_put", "infeed", "outfeed",
     "host_local_array_to_global_array", "global_array_to_host_local_array",
     "check",
@@ -177,7 +179,7 @@ def _walk_eqns(jaxpr, visit) -> None:
 
 
 def _sub_jaxprs(v) -> List[Any]:
-    core = jax.core
+    core = jax.extend.core
     if isinstance(v, core.ClosedJaxpr):
         return [v.jaxpr]
     if isinstance(v, core.Jaxpr):
@@ -193,7 +195,7 @@ def _sub_jaxprs(v) -> List[Any]:
 def _frame(eqn) -> Tuple[str, int]:
     """(repo-relative file, line) of the user code that emitted ``eqn``,
     or ("", 0) when no user frame survives."""
-    fr = source_info_util.user_frame(eqn.source_info)
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
     if fr is None:
         return "", 0
     rel = _relativize(fr.file_name)

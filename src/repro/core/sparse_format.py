@@ -26,6 +26,7 @@ abstract packed layouts for the dry-run without allocating.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Optional, Tuple
 
@@ -101,7 +102,8 @@ class BlockSparseWeight:
 
     def nbytes_dense(self) -> int:
         k, n = self.shape
-        return k * n * self.values.dtype.itemsize
+        return (math.prod(self.lead_shape) * k * n
+                * self.values.dtype.itemsize)
 
     def compression_ratio(self) -> float:
         """compressed bytes / dense bytes (lower is better)."""
@@ -114,11 +116,17 @@ class BlockSparseWeight:
 # ---------------------------------------------------------------------------
 
 def pack_nibbles(v: jax.Array) -> jax.Array:
-    """int8 ``[..., C]`` in [-8, 7] -> uint8 ``[..., C//2]`` (lo | hi<<4)."""
-    if v.shape[-1] % 2 != 0:
-        raise ValueError(f"nibble packing needs an even channel dim, got {v.shape[-1]}")
+    """int8 ``[..., C]`` in [-8, 7] -> uint8 ``[..., C//2]``.
+
+    Byte ``i`` holds value ``i`` in its low nibble and value ``i + C/2`` in
+    its high nibble: the two nibble planes are the two halves of the value
+    stream, which the int4 kernel stacks back with one row concatenation.
+    """
+    c = v.shape[-1]
+    if c % 2 != 0:
+        raise ValueError(f"nibble packing needs an even channel dim, got {c}")
     u = v.astype(jnp.uint8) & jnp.uint8(0xF)
-    lo, hi = u[..., 0::2], u[..., 1::2]
+    lo, hi = u[..., : c // 2], u[..., c // 2:]
     return lo | (hi << jnp.uint8(4))
 
 
@@ -131,8 +139,7 @@ def unpack_nibbles(b: jax.Array) -> jax.Array:
     lo = (b & jnp.uint8(0xF)).astype(jnp.int8)
     hi = (b >> jnp.uint8(4)).astype(jnp.int8)
     sext = lambda x: ((x ^ jnp.int8(8)) - jnp.int8(8)).astype(jnp.int8)
-    out = jnp.stack([sext(lo), sext(hi)], axis=-1)
-    return out.reshape(*b.shape[:-1], b.shape[-1] * 2)
+    return jnp.concatenate([sext(lo), sext(hi)], axis=-1)
 
 
 # ---------------------------------------------------------------------------

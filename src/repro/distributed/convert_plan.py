@@ -15,6 +15,7 @@ sparse under expert-parallel sharding (§Perf) or single-shard serving.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.core.sparse_format import (DEFAULT_BLOCK, BlockSparseWeight,
                                       packed_spec, balanced_capacity, pack,
-                                      pack_nibbles)
+                                      pack_nibbles, repack_capacity)
 from repro.core.convert import default_predicate, _path_str
 from repro.core.pruning import make_mask
 from repro.core.quant import quantize_weight_int8, quantize_weight_int4
@@ -31,8 +32,11 @@ from .sharding import ShardCtx, mesh_axis_size
 
 
 def _to_int4(sw: BlockSparseWeight) -> BlockSparseWeight:
-    """int8-valued packed weight -> nibble-packed int4 (capacity is a
-    multiple of 128, hence even)."""
+    """int8-valued packed weight -> nibble-packed int4.  The capacity is
+    grown to a multiple of 256 so each nibble plane fills whole 128-lane
+    rows of the int4 kernel's value tile."""
+    if sw.capacity % 256:
+        sw = repack_capacity(sw, -(-sw.capacity // 256) * 256)
     return BlockSparseWeight(sw.bitmap, pack_nibbles(sw.values), sw.scale,
                              sw.shape, sw.block, packed4=True)
 
@@ -106,6 +110,28 @@ def convert_abstract(params_abs: Any, spec_tree: Any, cfg, ctx: ShardCtx,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+@partial(jax.jit, static_argnames=("sparsity", "policy", "blk", "cap",
+                                   "pad", "mode"))
+def _prune_and_pack(leaf, *, sparsity, policy, blk, cap, pad, mode):
+    """Prune and pack one 2-D weight, or a layer-stacked one per layer.
+    Jitted so that each leaf geometry is one compiled program: run eagerly,
+    every primitive of the pruning and packing is dispatched (and on a TPU
+    compiled) on its own."""
+    def pack_one(w2):
+        mask = make_mask(w2, sparsity, policy, blk)
+        if mode in ("int8", "int4"):
+            quant = quantize_weight_int8 if mode == "int8" \
+                else quantize_weight_int4
+            q, scale = quant(jnp.where(mask, w2, 0))
+            sw = pack(q, mask, blk, capacity=cap,
+                      pad_to_blocks=pad, scale=scale)
+            return _to_int4(sw) if mode == "int4" else sw
+        return pack(w2.astype(jnp.bfloat16), mask, blk,
+                    capacity=cap, pad_to_blocks=pad)
+
+    return jax.vmap(pack_one)(leaf) if leaf.ndim == 3 else pack_one(leaf)
+
+
 def convert_concrete(params: Any, spec_tree: Any, cfg, ctx: ShardCtx,
                      mode: str = "bf16", block=DEFAULT_BLOCK) -> Any:
     """Real pruning + packing with the same mesh-aware plan (tests/serving)."""
@@ -119,24 +145,10 @@ def convert_concrete(params: Any, spec_tree: Any, cfg, ctx: ShardCtx,
         p = _path_str(path)
         if _is_sparsifiable(p, spec):
             blk, pad = _plan_leaf(spec, ctx, block)
-            cap = balanced_capacity(density, blk)
-
-            def pack_one(w2):
-                mask = make_mask(w2, cfg.sparsity, cfg.sparse_policy, blk)
-                if mode in ("int8", "int4"):
-                    quant = quantize_weight_int8 if mode == "int8" \
-                        else quantize_weight_int4
-                    q, scale = quant(jnp.where(mask, w2, 0))
-                    sw = pack(q, mask, blk, capacity=cap,
-                              pad_to_blocks=pad, scale=scale)
-                    return _to_int4(sw) if mode == "int4" else sw
-                return pack(w2.astype(jnp.bfloat16), mask, blk,
-                            capacity=cap, pad_to_blocks=pad)
-
-            if leaf.ndim == 3:          # layer-stacked: pack per layer
-                out.append(jax.vmap(pack_one)(leaf))
-            else:
-                out.append(pack_one(leaf))
+            out.append(_prune_and_pack(
+                leaf, sparsity=cfg.sparsity, policy=cfg.sparse_policy,
+                blk=blk, cap=balanced_capacity(density, blk), pad=pad,
+                mode=mode))
         else:
             out.append(leaf)
     return jax.tree_util.tree_unflatten(treedef, out)

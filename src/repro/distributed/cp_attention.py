@@ -38,7 +38,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.sparse_format import BlockSparseWeight, unpack
 from repro.core.sparse_kv import SparseKVCache
 from repro.kernels import ref
-from .sharding import shard_map
 
 
 def _local_partial(q, k_sp_leaves, v_sp_leaves, sw_meta, hkv, sm_scale):
@@ -116,7 +115,7 @@ def sparse_decode_attention_cp(q: jax.Array, cache: SparseKVCache,
             o_pref, _ = ref._merge_attn(o_pref, lse_pref, o_t, lse_t)
         return o_pref.reshape(bl, hq_l, d_l).astype(qL.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, blk5, blk5, blk5, blk5, tail_spec, tail_spec,
                   P()),

@@ -28,23 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:                                  # jax >= 0.5 top-level export
-    shard_map = jax.shard_map
-except AttributeError:                # jax 0.4.x experimental location
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=None, axis_names=None, **kw):
-        """Translate modern ``jax.shard_map`` kwargs (``check_vma``,
-        ``axis_names``) onto the 0.4.x experimental API (``check_rep``,
-        ``auto`` = complement of the manual axes)."""
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
-
 from repro.core.sparse_format import BlockSparseWeight
 from repro.models import module as mod
 

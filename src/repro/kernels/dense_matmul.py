@@ -19,8 +19,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .common import CompilerParams
-
 
 def _kernel(x_ref, w_ref, o_ref, acc_ref):
     @pl.when(pl.program_id(2) == 0)
@@ -37,9 +35,9 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref):
 
 
 @partial(jax.jit, static_argnames=("block", "out_dtype", "interpret"))
-def dense_matmul_pallas(x: jax.Array, w: jax.Array,
-                        block=(128, 256, 128), out_dtype=None,
-                        interpret: bool = True) -> jax.Array:
+def dense_matmul_pallas(x: jax.Array, w: jax.Array, *, interpret: bool,
+                        block=(128, 256, 128),
+                        out_dtype=None) -> jax.Array:
     """``x [M, K] @ w [K, N]`` with padding to block multiples."""
     tm, bk, bn = block
     m, k = x.shape
@@ -62,7 +60,7 @@ def dense_matmul_pallas(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((tm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, bn), acc_dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="dense_matmul",

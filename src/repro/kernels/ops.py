@@ -4,15 +4,16 @@ Backend policy (``set_backend`` / ``backend()`` context):
   * ``"tpu"``        — real Pallas lowering (requires TPU devices).
   * ``"interpret"``  — Pallas interpret mode: the kernel bodies execute in
                         Python on CPU; used to *validate* the kernels here.
-  * ``"xla"``        — pure-jnp reference semantics (fast on CPU; used by the
-                        multi-pod dry-run, where roofline terms are then
-                        kernel-adjusted — see benchmarks/roofline.py).
+  * ``"xla"``        — pure-jnp reference semantics (the CPU default; on a
+                        TPU it is the on-chip reference the kernels are
+                        checked against, e.g. by ``chip_smoke.py``).
 
 All entry points accept arbitrary leading batch dims on ``x``.
 """
 from __future__ import annotations
 
 import contextlib
+from functools import partial
 from typing import Optional
 
 import jax
@@ -28,7 +29,20 @@ from .sparse_gemv import sparse_gemv_pallas
 from .sparse_attention import (sparse_decode_attention_pallas,
                                sparse_decode_attention_fused_pallas)
 
-_BACKEND = "tpu" if jax.default_backend() == "tpu" else "xla"
+
+def _platform_backend() -> str:
+    """The backend a fresh process starts on: the real Pallas kernels on a
+    TPU, the jnp reference on the CPU.  Any other platform has neither and
+    is refused rather than silently served by the reference."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return "tpu"
+    if platform == "cpu":
+        return "xla"
+    raise RuntimeError(f"no kernel backend for platform {platform!r}")
+
+
+_BACKEND = _platform_backend()
 
 
 def set_backend(name: str) -> None:
@@ -50,6 +64,56 @@ def backend(name: str):
         yield
     finally:
         set_backend(prev)
+
+
+# Sharding context the Pallas kernels run under, set while a mesh-sharded
+# engine traces its steps.  XLA cannot partition a Mosaic kernel, so under
+# a mesh each kernel call is a ``shard_map`` whose operands keep the
+# serving placement (``distributed/serving_sharding``): attention splits
+# slots over the data axes and KV heads over the model axis, so the pooled
+# KV state is never gathered; a linear splits its rows over the data axes
+# against its weight, which the serving engine replicates.
+_SHARD = None
+
+# logical axes of the attention kernels' [B, Hkv, ...] operands
+_SLOT_HEAD = ("slots", "kv_heads", None, None)
+
+
+@contextlib.contextmanager
+def kernel_mesh(ctx):
+    """Trace-time context: run every Pallas kernel call on the mesh of the
+    ``ShardCtx`` ``ctx``, with operands placed by its logical-axis rules."""
+    global _SHARD
+    prev, _SHARD = _SHARD, ctx
+    try:
+        yield
+    finally:
+        _SHARD = prev
+
+
+def _run(kernel, args, axes, out_axes, dims, **static):
+    """Call a Pallas kernel entry; under :func:`kernel_mesh` as a
+    ``shard_map``.  ``axes`` holds one logical-axes tuple per operand
+    (``()`` keeps it whole on every device) and ``out_axes`` one per result;
+    ``dims`` gives each logical axis's global size, and an axis whose size
+    does not divide its mesh axes stays whole."""
+    if _SHARD is None:
+        return kernel(*args, **static)
+    spec = lambda ax: _SHARD.spec(ax, [dims.get(a, 1) for a in ax])  # noqa: E731
+    out_specs = (tuple(map(spec, out_axes)) if isinstance(out_axes, list)
+                 else spec(out_axes))
+    return jax.shard_map(partial(kernel, **static), mesh=_SHARD.mesh,
+                         in_specs=tuple(map(spec, axes)),
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def _run_linear(kernel, x2, *operands, **static):
+    """A linear kernel on ``x2 [M, K]`` (plus per-row int8 scales when
+    ``operands`` has two entries): rows follow the batch placement, the
+    weight is whole on every device."""
+    axes = [("batch", None)] + [("batch",)] * (len(operands) - 1) + [()]
+    return _run(kernel, (x2, *operands), axes, ("batch", None),
+                {"batch": x2.shape[0]}, **static)
 
 
 def _pallas() -> Optional[bool]:
@@ -74,7 +138,8 @@ def dense_matmul(x: jax.Array, w: jax.Array, out_dtype=None) -> jax.Array:
     if interp is None:
         out = ref.dense_matmul_ref(x2, w, out_dtype)
     else:
-        out = dense_matmul_pallas(x2, w, out_dtype=out_dtype, interpret=interp)
+        out = _run_linear(dense_matmul_pallas, x2, w, out_dtype=out_dtype,
+                          interpret=interp)
     return out.reshape(*lead, w.shape[-1])
 
 
@@ -85,10 +150,11 @@ def sparse_matmul(x: jax.Array, sw: BlockSparseWeight,
     if interp is None:
         out = ref.sparse_matmul_ref(x2, sw, out_dtype)
     elif x2.shape[0] <= 8:
-        out = sparse_gemv_pallas(x2, sw, out_dtype=out_dtype, interpret=interp)
+        out = _run_linear(sparse_gemv_pallas, x2, sw, out_dtype=out_dtype,
+                          interpret=interp)
     else:
-        out = sparse_matmul_pallas(x2, sw, out_dtype=out_dtype,
-                                   interpret=interp)
+        out = _run_linear(sparse_matmul_pallas, x2, sw, out_dtype=out_dtype,
+                          interpret=interp)
     return out.reshape(*lead, out.shape[-1])
 
 
@@ -103,11 +169,11 @@ def sparse_matmul_int8(x: jax.Array, sw: BlockSparseWeight,
         xq, sx = quantize_act_int8(x2)
         if sw.packed4:
             from .sparse_matmul_int4 import sparse_matmul_int4_pallas
-            out = sparse_matmul_int4_pallas(xq, sx, sw, out_dtype=out_dtype,
-                                            interpret=interp)
+            out = _run_linear(sparse_matmul_int4_pallas, xq, sx, sw,
+                              out_dtype=out_dtype, interpret=interp)
         else:
-            out = sparse_matmul_int8_pallas(xq, sx, sw, out_dtype=out_dtype,
-                                            interpret=interp)
+            out = _run_linear(sparse_matmul_int8_pallas, xq, sx, sw,
+                              out_dtype=out_dtype, interpret=interp)
     return out.reshape(*lead, out.shape[-1])
 
 
@@ -227,17 +293,20 @@ def sparse_decode_attention(q: jax.Array,
         if pad:
             k_tail = jnp.pad(k_tail, ((0, 0), (0, 0), (0, pad), (0, 0)))
             v_tail = jnp.pad(v_tail, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        o = sparse_decode_attention_fused_pallas(
-            qg, kbm, kvv, vbm, vvv, k_tail, v_tail, bs=bs,
-            sm_scale=sm_scale, interpret=interp, n_blocks=n_blocks,
-            tail_len=tl, group=g)
+        o = _run(sparse_decode_attention_fused_pallas,
+                 (qg, kbm, kvv, vbm, vvv, k_tail, v_tail, n_blocks, tl),
+                 [_SLOT_HEAD] * 7 + [("slots",)] * 2, _SLOT_HEAD,
+                 {"slots": b, "kv_heads": hkv},
+                 bs=bs, sm_scale=sm_scale, interpret=interp, group=g)
         if panel:
             return (o.reshape(b, hkv, qn, g, d).transpose(0, 2, 1, 3, 4)
                     .reshape(b, qn, hq, d).astype(q.dtype))
     else:
-        o, _ = sparse_decode_attention_pallas(
-            qg, kbm, kvv, vbm, vvv, bs=bs, sm_scale=sm_scale,
-            interpret=interp, n_blocks=n_blocks)
+        o, _ = _run(sparse_decode_attention_pallas,
+                    (qg, kbm, kvv, vbm, vvv, n_blocks),
+                    [_SLOT_HEAD] * 5 + [("slots",)], [_SLOT_HEAD] * 2,
+                    {"slots": b, "kv_heads": hkv},
+                    bs=bs, sm_scale=sm_scale, interpret=interp)
         if prefix_len is not None:
             # a fully-skipped prefix leaves the accumulator untouched
             empty_p = jnp.broadcast_to(jnp.atleast_1d(
@@ -311,10 +380,14 @@ def sparse_decode_attention_paged(q: jax.Array,
     if pad:
         k_tail = jnp.pad(k_tail, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v_tail = jnp.pad(v_tail, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    o = sparse_decode_attention_fused_pallas(
-        qg, k_bitmap, k_values, v_bitmap, v_values, k_tail, v_tail, bs=bs,
-        sm_scale=sm_scale, interpret=interp, n_blocks=n_blocks,
-        tail_len=tl, group=g, block_table=table)
+    arena = (None, "kv_heads", None)    # physical blocks reach every slot
+    o = _run(sparse_decode_attention_fused_pallas,
+             (qg, k_bitmap, k_values, v_bitmap, v_values, k_tail, v_tail,
+              n_blocks, tl, table),
+             [_SLOT_HEAD] + [arena] * 4 + [_SLOT_HEAD] * 2
+             + [("slots",)] * 2 + [("slots", None)], _SLOT_HEAD,
+             {"slots": b, "kv_heads": hkv},
+             bs=bs, sm_scale=sm_scale, interpret=interp, group=g)
     if panel:
         return (o.reshape(b, hkv, qn, g, d).transpose(0, 2, 1, 3, 4)
                 .reshape(b, qn, hq, d).astype(q.dtype))

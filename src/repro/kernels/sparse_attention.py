@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .common import CompilerParams, decompress_block
+from .common import decompress_block, tile_view
 
 NEG_INF = -1e30
 
@@ -55,23 +55,41 @@ NEG_INF = -1e30
 def _online_update(q, k_blk, v_blk, acc_ref, m_ref, l_ref, *, sm_scale,
                    valid=None):
     """One flash step: score a (bs, D) panel against the (G, D) query group
-    and fold it into the online-softmax scratch state."""
+    and fold it into the online-softmax scratch state (all 2-D: the row
+    statistics live broadcast over 128 lanes)."""
     s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
-    if valid is not None:                                    # (1, bs) mask
+    if valid is not None:                                    # (rows, bs) mask
         s = jnp.where(valid, s, NEG_INF)
-    m_prev = m_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))         # (G,)
+    m_prev = m_ref[:, :1]                                    # (G, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])                          # (G, bs)
+    p = jnp.exp(s - m_new)                                   # (G, bs)
     if valid is not None:
         p = jnp.where(valid, p, 0.0)
-    l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = (acc_ref[...] * alpha[:, None]
+    l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = (acc_ref[...] * alpha
                     + jnp.dot(p, v_blk,
                               preferred_element_type=jnp.float32))
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _init_state(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _prefix_step(q_ref, kbm_ref, kval_ref, vbm_ref, vval_ref, acc_ref,
+                 m_ref, l_ref, *, bs, d, sm_scale):
+    """Decompress one (bs, D) K and V prefix block and fold it in."""
+    k_blk = decompress_block(kbm_ref[0], kval_ref[0], bs, d,
+                             dtype=jnp.float32)                  # (bs, D)
+    v_blk = decompress_block(vbm_ref[0], vval_ref[0], bs, d,
+                             dtype=jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)                          # (rows, D)
+    _online_update(q, k_blk, v_blk, acc_ref, m_ref, l_ref, sm_scale=sm_scale)
 
 
 def _kernel(nb_ref, q_ref, kbm_ref, kval_ref, vbm_ref, vval_ref,
@@ -80,28 +98,30 @@ def _kernel(nb_ref, q_ref, kbm_ref, kval_ref, vbm_ref, vval_ref,
 
     @pl.when(s_idx == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
     # Per-slot valid-block count (pooled cache: each request fills only a
     # prefix of the fixed-capacity block storage).  Blocks past it are
     # skipped entirely — zero compute, zero softmax contribution.
-    @pl.when(s_idx < nb_ref[0, 0])
+    @pl.when(s_idx < nb_ref[pl.program_id(0)])
     def _block():
-        k_blk = decompress_block(kbm_ref[0, 0, 0], kval_ref[0, 0, 0], bs, d,
-                                 dtype=jnp.float32)              # (bs, D)
-        v_blk = decompress_block(vbm_ref[0, 0, 0], vval_ref[0, 0, 0], bs, d,
-                                 dtype=jnp.float32)              # (bs, D)
-        q = q_ref[0, 0].astype(jnp.float32)                      # (G, D)
-        _online_update(q, k_blk, v_blk, acc_ref, m_ref, l_ref,
-                       sm_scale=sm_scale)
+        _prefix_step(q_ref, kbm_ref, kval_ref, vbm_ref, vval_ref, acc_ref,
+                     m_ref, l_ref, bs=bs, d=d, sm_scale=sm_scale)
 
     @pl.when(s_idx == pl.num_programs(2) - 1)
     def _done():
-        l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[:, 0] + jnp.log(l_safe)).astype(lse_ref.dtype)
+        l_safe = jnp.maximum(l_ref[...], 1e-30)                  # (G, 128)
+        o_ref[0, 0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(l_safe)).astype(lse_ref.dtype)
+
+
+def _flat_views(b, hkv, sb, *leaves):
+    """``[B, Hkv, Sb, X]`` prefix leaves -> kernel tile views, plus the
+    index map that fetches block ``(slot, head, min(s, sb-1))``."""
+    views = [tile_view(a) for a in leaves]
+    blk = lambda bb, h, s, *_: ((bb * hkv + h) * sb + jnp.minimum(s, sb - 1),
+                                0, 0)
+    return views, blk
 
 
 @partial(jax.jit, static_argnames=("bs", "sm_scale", "interpret"))
@@ -109,8 +129,8 @@ def sparse_decode_attention_pallas(
         q: jax.Array,
         k_bitmap: jax.Array, k_values: jax.Array,
         v_bitmap: jax.Array, v_values: jax.Array,
-        bs: int, sm_scale: float, interpret: bool = True,
-        n_blocks: jax.Array | None = None):
+        n_blocks: jax.Array | None = None, *,
+        bs: int, sm_scale: float, interpret: bool):
     """Prefix-partial attention over the compressed cache.
 
     Kept for the context-parallel path (per-shard partials merge across
@@ -126,43 +146,38 @@ def sparse_decode_attention_pallas(
     """
     b, hkv, g, d = q.shape
     sb = k_bitmap.shape[2]
-    words = k_bitmap.shape[3]
-    ck, cv = k_values.shape[3], v_values.shape[3]
     if n_blocks is None:
         n_blocks = jnp.full((b,), sb, jnp.int32)
-    nb2 = n_blocks.astype(jnp.int32).reshape(b, 1)   # 2-D for SMEM
+    views, blk = _flat_views(b, hkv, sb, k_bitmap, k_values,
+                             v_bitmap, v_values)
+    bcast = lambda bb, h, s, *_: (bb, h, 0, 0)
 
     out, lse = pl.pallas_call(
         partial(_kernel, bs=bs, d=d, sm_scale=sm_scale),
-        grid=(b, hkv, sb),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, h, s: (bb, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, d), lambda bb, h, s: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, words), lambda bb, h, s: (bb, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, ck), lambda bb, h, s: (bb, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, words), lambda bb, h, s: (bb, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, cv), lambda bb, h, s: (bb, h, s, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, h, s: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda bb, h, s: (bb, h, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, sb),
+            in_specs=[pl.BlockSpec((1, 1, g, d), bcast)]
+            + [pl.BlockSpec((1,) + a.shape[1:], blk) for a in views],
+            out_specs=[
+                pl.BlockSpec((1, 1, g, d), bcast),
+                pl.BlockSpec((1, 1, g, 128), bcast),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((g, d), jnp.float32),
+                pltpu.VMEM((g, 128), jnp.float32),
+                pltpu.VMEM((g, 128), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, g, 128), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="sparse_decode_attention",
-    )(nb2, q, k_bitmap, k_values, v_bitmap, v_values)
-    return out, lse
+    )(n_blocks.astype(jnp.int32), q, *views)
+    return out, lse[..., 0]
 
 
 def _fused_kernel(nb_ref, tl_ref, q_ref, kbm_ref, kval_ref, vbm_ref,
@@ -171,11 +186,11 @@ def _fused_kernel(nb_ref, tl_ref, q_ref, kbm_ref, kval_ref, vbm_ref,
     """Prefix + tail in one sequential sweep.
 
     Steps ``[0, sb)`` walk the compressed prefix blocks (gated by the
-    per-slot valid-block count in SMEM); steps ``[sb, sb+tb)`` walk the
-    dense tail ring (gated per token by the per-slot ``tail_len`` in SMEM).
-    One online-softmax scratch state spans both phases, so the final step
-    writes the fully-normalized attention output — no lse ever leaves the
-    kernel.
+    per-slot valid-block count, a scalar-prefetch operand in SMEM); steps
+    ``[sb, sb+tb)`` walk the dense tail ring (gated per token by the
+    per-slot ``tail_len``, likewise in SMEM).  One online-softmax scratch
+    state spans both phases, so the final step writes the fully-normalized
+    attention output — no lse ever leaves the kernel.
 
     The query block is ``(Q*g, D)`` rows ordered query-major within the
     GQA group; tail validity is per row — panel query ``row // g`` sees
@@ -184,43 +199,37 @@ def _fused_kernel(nb_ref, tl_ref, q_ref, kbm_ref, kval_ref, vbm_ref,
     single-query mask ``token < tail_len``.
     """
     s_idx = pl.program_id(2)
+    slot = pl.program_id(0)
+    n_valid, t_len = nb_ref[slot], tl_ref[slot]
 
     @pl.when(s_idx == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
-    @pl.when(jnp.logical_and(s_idx < sb, s_idx < nb_ref[0, 0]))
+    @pl.when(jnp.logical_and(s_idx < sb, s_idx < n_valid))
     def _prefix_block():
-        k_blk = decompress_block(kbm_ref[0, 0, 0], kval_ref[0, 0, 0], bs, d,
-                                 dtype=jnp.float32)              # (bs, D)
-        v_blk = decompress_block(vbm_ref[0, 0, 0], vval_ref[0, 0, 0], bs, d,
-                                 dtype=jnp.float32)
-        q = q_ref[0, 0].astype(jnp.float32)                      # (Q*g, D)
-        _online_update(q, k_blk, v_blk, acc_ref, m_ref, l_ref,
-                       sm_scale=sm_scale)
+        _prefix_step(q_ref, kbm_ref, kval_ref, vbm_ref, vval_ref, acc_ref,
+                     m_ref, l_ref, bs=bs, d=d, sm_scale=sm_scale)
 
     tail_base = (s_idx - sb) * bs
     qg = q_ref.shape[2]
-    # per-row visibility limit: query j (= row // g) sees tail_len + j
-    row_q = jax.lax.broadcasted_iota(jnp.int32, (qg, 1), 0) // g
 
     @pl.when(jnp.logical_and(s_idx >= sb,
-                             tail_base < tl_ref[0, 0] + (qg // g - 1)))
+                             tail_base < t_len + (qg // g - 1)))
     def _tail_block():
         k_blk = kt_ref[0, 0].astype(jnp.float32)                 # (bs, D)
         v_blk = vt_ref[0, 0].astype(jnp.float32)
         q = q_ref[0, 0].astype(jnp.float32)
-        tok = tail_base + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        # per-row visibility limit: query j (= row // g) sees tail_len + j
+        row_q = jax.lax.broadcasted_iota(jnp.int32, (qg, bs), 0) // g
+        tok = tail_base + jax.lax.broadcasted_iota(jnp.int32, (qg, bs), 1)
         _online_update(q, k_blk, v_blk, acc_ref, m_ref, l_ref,
-                       sm_scale=sm_scale,
-                       valid=tok < tl_ref[0, 0] + row_q)
+                       sm_scale=sm_scale, valid=tok < t_len + row_q)
 
     @pl.when(s_idx == pl.num_programs(2) - 1)
     def _done():
-        l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        l_safe = jnp.maximum(l_ref[:, :1], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
 def _fused_kernel_paged(tbl_ref, *refs, **kw):
@@ -238,11 +247,11 @@ def sparse_decode_attention_fused_pallas(
         k_bitmap: jax.Array, k_values: jax.Array,
         v_bitmap: jax.Array, v_values: jax.Array,
         k_tail: jax.Array, v_tail: jax.Array,
-        bs: int, sm_scale: float, interpret: bool = True,
         n_blocks: jax.Array | None = None,
         tail_len: jax.Array | None = None,
-        group: int | None = None,
-        block_table: jax.Array | None = None) -> jax.Array:
+        block_table: jax.Array | None = None, *,
+        bs: int, sm_scale: float, interpret: bool,
+        group: int | None = None) -> jax.Array:
     """Fused prefix+tail flash-decode: final attention in ONE pallas_call.
 
     q:             [B, Hkv, Q*G, D] query panel, rows ordered query-major
@@ -272,6 +281,9 @@ def sparse_decode_attention_fused_pallas(
                    (``< n_phys``) even past ``n_blocks`` — dead fetches
                    are gated off the softmax by the same ``n_blocks``
                    check as the flat path, so they are never *read*.
+    Per-slot ``n_blocks``/``tail_len`` (and the table) are scalar-prefetch
+    operands; every block is fetched as a tile whose last two dims are the
+    array's own (:func:`repro.kernels.common.tile_view`).
     Returns out [B, Hkv, Q*G, D] f32 — softmax-normalized over the union
     of valid prefix and tail positions (all-empty slots return zeros).
     """
@@ -280,101 +292,54 @@ def sparse_decode_attention_fused_pallas(
     if qg % g != 0:
         raise ValueError(f"query panel {qg} not a multiple of group {g}")
     paged = block_table is not None
+    leaves = (k_bitmap, k_values, v_bitmap, v_values)
     if paged:
         if k_bitmap.ndim != 3:   # [n_phys, Hkv, X] arena
             raise ValueError(f"paged arena must be rank-3, got {k_bitmap.shape}")
         sb = block_table.shape[1]
-        # rank-4 views so the block shapes match the flat layout's
-        # (1, 1, 1, X) fetches: physical block axis leads, Hkv second
-        k_bitmap, k_values, v_bitmap, v_values = (
-            a[:, :, None, :] for a in (k_bitmap, k_values,
-                                       v_bitmap, v_values))
+        views = [tile_view(a) for a in leaves]
+        # THE paged change: the prefix phase's block index goes through the
+        # table (physical block major, KV head minor in the arena view)
+        pre = lambda bb, h, s, tbl, nb, tl: (
+            tbl[bb, jnp.minimum(s, sb - 1)] * hkv + h, 0, 0)
     else:
         sb = k_bitmap.shape[2]
+        views, pre = _flat_views(b, hkv, sb, *leaves)
     tp = k_tail.shape[2]
     if not (sb >= 1 and tp >= bs and tp % bs == 0):
         raise ValueError(f"bad geometry: sb={sb}, tail={tp}, block={bs}")
     tb = tp // bs
-    words = k_bitmap.shape[3]
-    ck, cv = k_values.shape[3], v_values.shape[3]
     if n_blocks is None:
         n_blocks = jnp.full((b,), sb, jnp.int32)
     if tail_len is None:
         tail_len = jnp.full((b,), tp, jnp.int32)
-    nb2 = n_blocks.astype(jnp.int32).reshape(b, 1)   # 2-D for SMEM
-    tl2 = tail_len.astype(jnp.int32).reshape(b, 1)
-
-    common = dict(
-        out_shape=jax.ShapeDtypeStruct((b, hkv, qg, d), jnp.float32),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )
-    scratch = [
-        pltpu.VMEM((qg, d), jnp.float32),
-        pltpu.VMEM((qg, 128), jnp.float32),
-        pltpu.VMEM((qg, 128), jnp.float32),
-    ]
-
+    scalars = [n_blocks.astype(jnp.int32), tail_len.astype(jnp.int32)]
     if paged:
-        # THE paged change: the prefix phase's block index goes through the
-        # table.  Clamped on tail-phase steps like the flat path (the
-        # fetched block is ignored there — the pl.when gates never fire).
-        pre = lambda bb, h, s, tbl: (tbl[bb, jnp.minimum(s, sb - 1)],
-                                     h, 0, 0)
-        tail = lambda bb, h, s, tbl: (bb, h, jnp.maximum(s - sb, 0), 0)
-        smem = lambda bb, h, s, tbl: (bb, 0)
-        bcast = lambda bb, h, s, tbl: (bb, h, 0, 0)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, hkv, sb + tb),
-            in_specs=[
-                pl.BlockSpec((1, 1), smem, memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1), smem, memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1, qg, d), bcast),
-                pl.BlockSpec((1, 1, 1, words), pre),
-                pl.BlockSpec((1, 1, 1, ck), pre),
-                pl.BlockSpec((1, 1, 1, words), pre),
-                pl.BlockSpec((1, 1, 1, cv), pre),
-                pl.BlockSpec((1, 1, bs, d), tail),
-                pl.BlockSpec((1, 1, bs, d), tail),
-            ],
-            out_specs=pl.BlockSpec((1, 1, qg, d), bcast),
-            scratch_shapes=scratch,
-        )
-        return pl.pallas_call(
-            partial(_fused_kernel_paged, bs=bs, d=d, sm_scale=sm_scale,
-                    sb=sb, g=g),
-            grid_spec=grid_spec,
-            name="sparse_decode_attention_fused_paged",
-            **common,
-        )(block_table.astype(jnp.int32), nb2, tl2, q,
-          k_bitmap, k_values, v_bitmap, v_values, k_tail, v_tail)
+        scalars.insert(0, block_table.astype(jnp.int32))
 
     # index maps clamp into range on the other phase's steps (the fetched
     # block is ignored there — the pl.when gates never fire)
-    pre = lambda bb, h, s: (bb, h, jnp.minimum(s, sb - 1), 0)
-    tail = lambda bb, h, s: (bb, h, jnp.maximum(s - sb, 0), 0)
-
-    out = pl.pallas_call(
-        partial(_fused_kernel, bs=bs, d=d, sm_scale=sm_scale, sb=sb, g=g),
-        grid=(b, hkv, sb + tb),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, h, s: (bb, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda bb, h, s: (bb, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, qg, d), lambda bb, h, s: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, words), pre),
-            pl.BlockSpec((1, 1, 1, ck), pre),
-            pl.BlockSpec((1, 1, 1, words), pre),
-            pl.BlockSpec((1, 1, 1, cv), pre),
-            pl.BlockSpec((1, 1, bs, d), tail),
-            pl.BlockSpec((1, 1, bs, d), tail),
-        ],
-        out_specs=pl.BlockSpec((1, 1, qg, d), lambda bb, h, s: (bb, h, 0, 0)),
-        scratch_shapes=scratch,
-        name="sparse_decode_attention_fused",
-        **common,
-    )(nb2, tl2, q, k_bitmap, k_values, v_bitmap, v_values, k_tail, v_tail)
-    return out
+    tail = lambda bb, h, s, *_: (bb, h, jnp.maximum(s - sb, 0), 0)
+    bcast = lambda bb, h, s, *_: (bb, h, 0, 0)
+    kernel = _fused_kernel_paged if paged else _fused_kernel
+    return pl.pallas_call(
+        partial(kernel, bs=bs, d=d, sm_scale=sm_scale, sb=sb, g=g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(b, hkv, sb + tb),
+            in_specs=[pl.BlockSpec((1, 1, qg, d), bcast)]
+            + [pl.BlockSpec((1,) + a.shape[1:], pre) for a in views]
+            + [pl.BlockSpec((1, 1, bs, d), tail)] * 2,
+            out_specs=pl.BlockSpec((1, 1, qg, d), bcast),
+            scratch_shapes=[
+                pltpu.VMEM((qg, d), jnp.float32),
+                pltpu.VMEM((qg, 128), jnp.float32),
+                pltpu.VMEM((qg, 128), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, qg, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=("sparse_decode_attention_fused_paged" if paged
+              else "sparse_decode_attention_fused"),
+    )(*scalars, q, *views, k_tail, v_tail)

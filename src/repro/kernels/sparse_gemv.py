@@ -22,7 +22,7 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.sparse_format import BlockSparseWeight
-from .common import CompilerParams, decompress_block
+from .common import decompress_block, tile_view
 
 
 def _kernel(x_ref, bm_ref, val_ref, o_ref, acc_ref, *, bk, bn):
@@ -30,9 +30,8 @@ def _kernel(x_ref, bm_ref, val_ref, o_ref, acc_ref, *, bk, bn):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_tile = decompress_block(bm_ref[0, 0], val_ref[0, 0], bk, bn,
+    w_tile = decompress_block(bm_ref[0], val_ref[0], bk, bn,
                               dtype=jnp.float32)
-    # vector path: broadcast-multiply-accumulate (VPU), not an MXU pass
     x = x_ref[...].astype(jnp.float32)                # (tm, bk)
     acc_ref[...] += jax.lax.dot_general(
         x, w_tile, (((1,), (0,)), ((), ())),
@@ -44,12 +43,11 @@ def _kernel(x_ref, bm_ref, val_ref, o_ref, acc_ref, *, bk, bn):
 
 
 @partial(jax.jit, static_argnames=("out_dtype", "interpret"))
-def sparse_gemv_pallas(x: jax.Array, sw: BlockSparseWeight,
-                       out_dtype=None, interpret: bool = True) -> jax.Array:
+def sparse_gemv_pallas(x: jax.Array, sw: BlockSparseWeight, *,
+                       interpret: bool, out_dtype=None) -> jax.Array:
     """``x [M<=8, K] @ unpack(sw)`` — batch-1..8 decode path."""
     bk, bn = sw.block
-    kb, nb, words = sw.bitmap.shape
-    cap = sw.capacity
+    kb, nb, _ = sw.bitmap.shape
     m, k = x.shape
     tm = 8
     if m > tm:
@@ -57,21 +55,23 @@ def sparse_gemv_pallas(x: jax.Array, sw: BlockSparseWeight,
     kp = kb * bk
     x = jnp.pad(x, ((0, tm - m), (0, kp - k)))
     out_dtype = out_dtype or x.dtype
+    bm, vals = tile_view(sw.bitmap), tile_view(sw.values)
+    blk = lambda j, kk: (kk * nb + j, 0, 0)
 
     out = pl.pallas_call(
         partial(_kernel, bk=bk, bn=bn),
         grid=(nb, kb),
         in_specs=[
             pl.BlockSpec((tm, bk), lambda j, kk: (0, kk)),
-            pl.BlockSpec((1, 1, words), lambda j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, cap), lambda j, kk: (kk, j, 0)),
+            pl.BlockSpec((1,) + bm.shape[1:], blk),
+            pl.BlockSpec((1,) + vals.shape[1:], blk),
         ],
         out_specs=pl.BlockSpec((tm, bn), lambda j, kk: (0, j)),
         out_shape=jax.ShapeDtypeStruct((tm, nb * bn), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="sparse_gemv",
-    )(x, sw.bitmap, sw.values)
+    )(x, bm, vals)
     return out[:m, : sw.shape[1]]

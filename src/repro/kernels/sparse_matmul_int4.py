@@ -3,8 +3,12 @@ prescribed: "dequantizing INT4 values into INT8 before computation".
 
 Identical structure to :mod:`sparse_matmul_int8`, with one extra VMEM stage:
 the packed nibble stream (two weights/byte — HBM traffic halves again vs
-int8) is expanded to int8 in registers *before* the bitmap decompression,
-then the int8 MXU path runs unchanged.
+int8) is expanded in registers *before* the bitmap decompression, then the
+int8 MXU path runs unchanged.  Byte ``i`` of a block holds value ``i`` in
+its low nibble and value ``i + C/2`` in its high nibble
+(:func:`repro.core.sparse_format.pack_nibbles`), so the two nibble planes
+of the ``(C/256, 128)`` byte tile stack into the ``(C/128, 128)`` value
+tile with one row concatenation.
 """
 from __future__ import annotations
 
@@ -13,18 +17,18 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
-import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.sparse_format import BlockSparseWeight
-from .common import CompilerParams, decompress_block
+from .common import decompress_block, tile_view
+from .sparse_matmul_int8 import int8_matmul_call
 
 
 def _unpack_nibbles(b):
-    lo = (b & jnp.uint8(0xF)).astype(jnp.int8)
-    hi = (b >> jnp.uint8(4)).astype(jnp.int8)
-    sext = lambda x: ((x ^ jnp.int8(8)) - jnp.int8(8)).astype(jnp.int8)
-    out = jnp.stack([sext(lo), sext(hi)], axis=-1)
-    return out.reshape(*b.shape[:-1], b.shape[-1] * 2)
+    """uint8 ``(C/256, 128)`` -> sign-extended int32 ``(C/128, 128)``."""
+    b = b.astype(jnp.int32)
+    sext = lambda x: (x ^ 8) - 8
+    # in-VMEM tile assembly, not an HBM concat
+    return jnp.concatenate([sext(b & 0xF), sext((b >> 4) & 0xF)], axis=0)  # jitlint: disable=hot-path-op
 
 
 def _kernel(x_ref, sx_ref, bm_ref, val_ref, sw_ref, o_ref, acc_ref, *,
@@ -33,51 +37,28 @@ def _kernel(x_ref, sx_ref, bm_ref, val_ref, sw_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    vals_i8 = _unpack_nibbles(val_ref[0, 0])          # int4 -> int8 in VMEM
-    w_tile = decompress_block(bm_ref[0, 0], vals_i8, bk, bn, dtype=jnp.int8)
-    acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.int8), w_tile,
+    vals = _unpack_nibbles(val_ref[0]).astype(jnp.bfloat16)  # exact in bf16
+    w_tile = decompress_block(bm_ref[0], vals, bk, bn, dtype=jnp.int8)
+    acc_ref[...] += jnp.dot(x_ref[...], w_tile,
                             preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _done():
         scaled = (acc_ref[...].astype(jnp.float32)
-                  * sx_ref[...] * sw_ref[0][None, :])
+                  * sx_ref[...] * sw_ref[...])
         o_ref[...] = scaled.astype(o_ref.dtype)
 
 
 @partial(jax.jit, static_argnames=("tm", "out_dtype", "interpret"))
 def sparse_matmul_int4_pallas(xq: jax.Array, sx: jax.Array,
-                              sw: BlockSparseWeight,
-                              tm: int = 128, out_dtype=jnp.float32,
-                              interpret: bool = True) -> jax.Array:
+                              sw: BlockSparseWeight, *, interpret: bool,
+                              tm: int = 128,
+                              out_dtype=jnp.float32) -> jax.Array:
     """``dequant(xq, sx) @ dequant4(sw)``; xq int8 [M, K], sx f32 [M]."""
     if not (sw.packed4 and sw.scale is not None):
         raise ValueError("int4 path needs nibble-packed values and a scale")
-    bk, bn = sw.block
-    kb, nb, words = sw.bitmap.shape
-    cap_packed = sw.values.shape[-1]
-    m, k = xq.shape
-    kp, mp = kb * bk, -(-m // tm) * tm
-    xq = jnp.pad(xq, ((0, mp - m), (0, kp - k)))
-    sx2 = jnp.pad(sx.astype(jnp.float32), (0, mp - m))[:, None]
-    w_scale = sw.scale.reshape(nb, bn)
-
-    out = pl.pallas_call(
-        partial(_kernel, bk=bk, bn=bn),
-        grid=(mp // tm, nb, kb),
-        in_specs=[
-            pl.BlockSpec((tm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((tm, 1), lambda i, j, kk: (i, 0)),
-            pl.BlockSpec((1, 1, words), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, cap_packed), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((tm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, nb * bn), out_dtype),
-        scratch_shapes=[pltpu.VMEM((tm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="sparse_matmul_int4",
-    )(xq, sx2, sw.bitmap, sw.values, w_scale)
-    return out[:m, : sw.shape[1]]
+    if sw.values.shape[-1] % 128:
+        raise ValueError(f"int4 kernel needs a capacity multiple of 256, got "
+                         f"{sw.capacity}")
+    return int8_matmul_call(_kernel, xq, sx, sw, tile_view(sw.values), tm,
+                            out_dtype, interpret, "sparse_matmul_int4")

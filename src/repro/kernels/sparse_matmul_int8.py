@@ -17,7 +17,7 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.sparse_format import BlockSparseWeight
-from .common import CompilerParams, decompress_block
+from .common import decompress_block, tile_view
 
 
 def _kernel(x_ref, sx_ref, bm_ref, val_ref, sw_ref, o_ref, acc_ref, *, bk, bn):
@@ -25,52 +25,60 @@ def _kernel(x_ref, sx_ref, bm_ref, val_ref, sw_ref, o_ref, acc_ref, *, bk, bn):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    w_tile = decompress_block(bm_ref[0, 0], val_ref[0, 0], bk, bn,
-                              dtype=jnp.int8)
-    acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.int8), w_tile,
+    w_tile = decompress_block(bm_ref[0], val_ref[0], bk, bn, dtype=jnp.int8)
+    acc_ref[...] += jnp.dot(x_ref[...], w_tile,
                             preferred_element_type=jnp.int32)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _done():
         scaled = (acc_ref[...].astype(jnp.float32)
                   * sx_ref[...]                      # (tm, 1) per-row act scale
-                  * sw_ref[0][None, :])              # (bn,) per-channel w scale
+                  * sw_ref[...])                     # (1, bn) per-channel w scale
         o_ref[...] = scaled.astype(o_ref.dtype)
 
 
-@partial(jax.jit, static_argnames=("tm", "out_dtype", "interpret"))
-def sparse_matmul_int8_pallas(xq: jax.Array, sx: jax.Array,
-                              sw: BlockSparseWeight,
-                              tm: int = 128, out_dtype=jnp.float32,
-                              interpret: bool = True) -> jax.Array:
-    """``dequant(xq, sx) @ dequant(sw)``; xq int8 [M, K], sx f32 [M]."""
-    if not (sw.values.dtype == jnp.int8 and sw.scale is not None):
-        raise ValueError("int8 path needs int8 values and a scale")
+def int8_matmul_call(kernel, xq, sx, sw: BlockSparseWeight, vals, tm,
+                     out_dtype, interpret, name):
+    """The int8/int4 GEMM grid: ``vals`` is the kernel's view of the packed
+    values (int8 for int8, nibble bytes for int4)."""
     bk, bn = sw.block
-    kb, nb, words = sw.bitmap.shape
-    cap = sw.capacity
+    kb, nb, _ = sw.bitmap.shape
     m, k = xq.shape
     kp, mp = kb * bk, -(-m // tm) * tm
     xq = jnp.pad(xq, ((0, mp - m), (0, kp - k)))
     sx2 = jnp.pad(sx.astype(jnp.float32), (0, mp - m))[:, None]
-    w_scale = sw.scale.reshape(nb, bn)
+    w_scale = sw.scale.reshape(1, nb * bn)
+    bm = tile_view(sw.bitmap)
+    blk = lambda i, j, kk: (kk * nb + j, 0, 0)
 
     out = pl.pallas_call(
-        partial(_kernel, bk=bk, bn=bn),
+        partial(kernel, bk=bk, bn=bn),
         grid=(mp // tm, nb, kb),
         in_specs=[
             pl.BlockSpec((tm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((tm, 1), lambda i, j, kk: (i, 0)),
-            pl.BlockSpec((1, 1, words), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, 1, cap), lambda i, j, kk: (kk, j, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (j, 0)),
+            pl.BlockSpec((1,) + bm.shape[1:], blk),
+            pl.BlockSpec((1,) + vals.shape[1:], blk),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((tm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, nb * bn), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="sparse_matmul_int8",
-    )(xq, sx2, sw.bitmap, sw.values, w_scale)
+        name=name,
+    )(xq, sx2, bm, vals, w_scale)
     return out[:m, : sw.shape[1]]
+
+
+@partial(jax.jit, static_argnames=("tm", "out_dtype", "interpret"))
+def sparse_matmul_int8_pallas(xq: jax.Array, sx: jax.Array,
+                              sw: BlockSparseWeight, *, interpret: bool,
+                              tm: int = 128,
+                              out_dtype=jnp.float32) -> jax.Array:
+    """``dequant(xq, sx) @ dequant(sw)``; xq int8 [M, K], sx f32 [M]."""
+    if not (sw.values.dtype == jnp.int8 and sw.scale is not None):
+        raise ValueError("int8 path needs int8 values and a scale")
+    return int8_matmul_call(_kernel, xq, sx, sw, tile_view(sw.values), tm,
+                            out_dtype, interpret, "sparse_matmul_int8")

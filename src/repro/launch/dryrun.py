@@ -278,7 +278,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     else:
         fn, args = build_decode(cfg, ctx, shape, mode)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -286,8 +286,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else None
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 

@@ -11,7 +11,7 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,12 +20,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     is the DCN-crossing outer data-parallel axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     """Arbitrary mesh (tests, elastic restore onto different topologies)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(data: int = 1, model: int = 1) -> Optional[Mesh]:
@@ -34,4 +34,5 @@ def make_test_mesh(data: int = 1, model: int = 1) -> Optional[Mesh]:
     if data * model > n:
         return None
     devs = np.array(jax.devices()[: data * model]).reshape(data, model)
-    return Mesh(devs, ("data", "model"))
+    return Mesh(devs, ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)

@@ -141,7 +141,7 @@ def run_probe(arch: str, shape_name: str, multi_pod: bool = False,
     ctx = ShardCtx(mesh, rules)
     t0 = time.time()
     fn, args, fwd_probe = build_period_probe(cfg, ctx, shape, mode)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         compiled = lowered.compile()
     cost = compiled.cost_analysis()
@@ -149,7 +149,7 @@ def run_probe(arch: str, shape_name: str, multi_pod: bool = False,
     fwd_cost = None
     if fwd_probe is not None:
         jfwd, fargs = fwd_probe
-        with mesh:
+        with jax.set_mesh(mesh):
             fwd_cost = jfwd.lower(*fargs).compile().cost_analysis()
     rec = {
         "arch": arch, "shape": shape_name, "mode": mode,

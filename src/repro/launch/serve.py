@@ -41,6 +41,7 @@ resumes at full cache-hit rate).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -51,9 +52,34 @@ from repro.configs import get_config
 from repro.data import DataConfig, host_batch
 from repro.distributed import ShardCtx, NULL_CTX, default_rules
 from repro.distributed.convert_plan import convert_concrete
+from repro.kernels import ops
+from repro.launch.runtime import enable_compile_cache, peak_bytes_in_use
 from repro.models import lm
 from repro.serving import (Engine, ContinuousEngine, SamplingParams,
                            SpecConfig, stable_trace_counts)
+
+
+def load_model(arch: str, *, reduced: bool = False, sparsity: float = 0.5,
+               dense: bool = False, int8: bool = False, seed: int = 0):
+    """Config + random weights from ``seed``, converted to the compressed
+    sparse format unless ``dense`` -> ``(cfg, params)``."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
+    cfg = dataclasses.replace(cfg, sparsity=sparsity)
+    params = lm.init_params(cfg, jax.random.PRNGKey(seed))
+    if not dense:
+        specs = lm.model_specs(cfg)
+        params = convert_concrete(params, specs, cfg, NULL_CTX,
+                                  mode="int8" if int8 else "bf16")
+        from repro.core import sparsity_report
+        rep = sparsity_report(params)
+        tot_d = sum(r["dense_bytes"] for r in rep.values())
+        tot_c = sum(r["compressed_bytes"] for r in rep.values())
+        print(f"[serve] sparse-converted {len(rep)} weights: "
+              f"{tot_d/1e6:.1f}MB -> {tot_c/1e6:.1f}MB "
+              f"({tot_c/tot_d:.3f}x)")
+    return cfg, params
 
 
 def main(argv=None):
@@ -204,24 +230,13 @@ def main(argv=None):
                  "drives its own synthetic warmup + stream; run the "
                  "retrace audit without --server)")
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    import dataclasses
-    cfg = dataclasses.replace(cfg, sparsity=args.sparsity)
-
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    if not args.dense:
-        specs = lm.model_specs(cfg)
-        params = convert_concrete(params, specs, cfg, NULL_CTX,
-                                  mode="int8" if args.int8 else "bf16")
-        from repro.core import sparsity_report
-        rep = sparsity_report(params)
-        tot_d = sum(r["dense_bytes"] for r in rep.values())
-        tot_c = sum(r["compressed_bytes"] for r in rep.values())
-        print(f"[serve] sparse-converted {len(rep)} weights: "
-              f"{tot_d/1e6:.1f}MB -> {tot_c/1e6:.1f}MB "
-              f"({tot_c/tot_d:.3f}x)")
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[serve] device: {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}; kernel backend {ops.get_backend()}")
+    cfg, params = load_model(args.arch, reduced=args.reduced,
+                             sparsity=args.sparsity, dense=args.dense,
+                             int8=args.int8)
 
     n_req = args.requests or args.batch
     dc = DataConfig(vocab=cfg.vocab, seq_len=args.prompt_len,
@@ -445,6 +460,9 @@ def main(argv=None):
                if lp is not None]
         print(f"[serve] mean chosen-token logprob: {np.mean(lps):.3f} "
               f"({len(lps)} tokens)")
+        peak = peak_bytes_in_use()
+        if peak is not None:
+            print(f"[serve] device peak_bytes_in_use: {peak}")
     if obs is not None:
         if not args.log_json:
             print(obs.report_line())

@@ -23,7 +23,6 @@ from repro.core.sparse_format import BlockSparseWeight, unpack
 from repro.kernels import ops
 from .module import ParamSpec
 from .layers import mlp_specs, mlp_apply
-from repro.distributed.sharding import shard_map
 
 
 def moe_specs(cfg) -> Dict[str, ParamSpec]:
@@ -152,8 +151,8 @@ def moe_apply(p, x: jax.Array, cfg, ctx) -> jax.Array:
                 out = jax.lax.psum(out, tp)
         return out
 
-    fn = shard_map(body, mesh=mesh, in_specs=(p_specs, x_spec),
-                       out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(p_specs, x_spec),
+                           out_specs=x_spec, check_vma=False)
     return fn(moe_p, x)
 
 
@@ -253,6 +252,6 @@ def moe_apply_ep(p, x: jax.Array, cfg, ctx):
             out = jax.lax.psum(out, dp)
         return out.astype(x.dtype)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(p_specs, x_spec),
-                       out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(p_specs, x_spec),
+                           out_specs=x_spec, check_vma=False)
     return fn(moe_p, x)

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core.sparse_kv import SparseKVCache, freeze_prefix
 from repro.distributed import NULL_CTX, serving_sharding
+from repro.kernels import ops
 from repro.models import lm
 from repro.models.attention import DenseKVCache
 
@@ -399,7 +400,14 @@ class ContinuousEngine:
             self.state = jax.device_put(self.state, st_sh)
 
             def _jit(fn, in_s, out_s):
-                return jax.jit(fn, in_shardings=in_s, out_shardings=out_s)
+                # the Pallas kernels cannot be partitioned by XLA: they
+                # trace as shard_maps that keep the serving placement
+                # (kernels/ops.py)
+                def on_mesh(*args):
+                    with ops.kernel_mesh(ctx):
+                        return fn(*args)
+                return jax.jit(on_mesh, in_shardings=in_s,
+                               out_shardings=out_s)
         elif self.pool.checkify:
             # sanitized mode: the pool transitions plant checkify.check
             # invariants, which a plain jit cannot trace — functionalize

@@ -18,7 +18,6 @@ from jax.sharding import PartitionSpec as P
 from repro.models import lm
 from repro.optim import (OptConfig, adamw_step, init_opt_state,
                          compress_and_reduce)
-from repro.distributed.sharding import shard_map
 
 
 def chunked_ce_loss(params, hidden: jax.Array, labels: jax.Array,
@@ -142,7 +141,7 @@ def make_compressed_grads(cfg, ctx, scheme: str = "bf16",
     rep = lambda tree: jax.tree_util.tree_map(lambda _: P(), tree)
 
     def fn(params, err_state, batch):
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(rep(params),
                       jax.tree_util.tree_map(lambda _: P(dp), err_state),

@@ -100,6 +100,24 @@ def test_fused_kernel_edge_grid(prefix_blocks, tail_len, ks, vs):
                                    rtol=1e-4, atol=1e-4)
 
 
+def test_fused_kernel_head_dim_96():
+    """KV blocks whose width does not divide 128 (phi3-mini's head_dim 96):
+    a (64, 96) block's bitmap rows straddle two rows of the word view."""
+    bs, d, hkv, g = 64, 96, 2, 2
+    q, k_sp, v_sp, k_tail, v_tail = _pooled_case(bs=bs, d=d, hkv=hkv, g=g,
+                                                 sb=2, t=64)
+    tl = jnp.asarray([0, 5, 64, 17], jnp.int32)
+    pl_ = jnp.asarray([2, 1, 0, 2], jnp.int32) * bs
+    sm = 1.0 / d ** 0.5
+    with ops.backend("interpret"):
+        o_kernel = ops.sparse_decode_attention(
+            q, k_sp, v_sp, hkv, sm, k_tail, v_tail, tl, prefix_len=pl_)
+    o_fused = ref.sparse_decode_attention_fused_ref(
+        q, k_sp, v_sp, sm, k_tail, v_tail, tl, prefix_len=pl_)
+    np.testing.assert_allclose(np.asarray(o_kernel), np.asarray(o_fused),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_fused_kernel_unaligned_tail_ring_is_padded():
     """A tail ring that is not a whole number of (bs,)-panels (legacy
     one-shot cache geometry) is zero-padded by the dispatcher; the padding

@@ -26,6 +26,25 @@ def make_sparse(k, n, sparsity=0.5, block=(128, 128), dtype=jnp.float32,
     return jnp.where(mask, w, 0), pack(w, mask, block)
 
 
+@pytest.mark.parametrize("block", [(256, 128), (16, 32), (64, 96),
+                                   (128, 96)])
+@pytest.mark.parametrize("sparsity", [0.0, 0.5])
+def test_decompress_block_matches_unpack(block, sparsity):
+    """The in-kernel decompression rebuilds each block exactly as
+    ``unpack`` does, for widths that divide 128 and for head_dim 96, whose
+    bitmap rows straddle two rows of the 128-lane word view."""
+    from repro.core.sparse_format import unpack
+    from repro.kernels.common import decompress_block, tile_view
+    bk, bn = block
+    _, sw = make_sparse(bk, 2 * bn, sparsity, block, seed=3)
+    words, vals = tile_view(sw.bitmap), tile_view(sw.values)
+    dense = np.asarray(unpack(sw))
+    for j in range(2):
+        got = decompress_block(words[j], vals[j], bk, bn)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      dense[:, j * bn:(j + 1) * bn])
+
+
 @pytest.mark.parametrize("m,k,n", [(8, 128, 128), (16, 256, 384),
                                    (128, 512, 256), (5, 200, 100)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -113,3 +113,32 @@ def test_linear_one_route_per_leaf_type(xw, routes):
         ops.linear(x, leaf)
     assert routes == ["dense_matmul", "sparse_matmul",
                       "sparse_matmul_int8", "sparse_matmul_int8"]
+
+
+def test_platform_backend_is_the_reference_on_cpu():
+    """A CPU process starts on the jnp reference; only a TPU starts on the
+    real kernels (and any other platform is refused)."""
+    assert ops._platform_backend() == "xla"
+
+
+def test_kernel_mesh_wraps_kernels_in_shard_map(xw):
+    """Under ``kernel_mesh`` every Pallas call traces as a ``shard_map``
+    (Mosaic kernels cannot be partitioned by XLA) whose rows follow the
+    batch placement, and computes what the bare call computes.  The
+    multi-device placement and token parity are checked by
+    ``test_serving_sharded.py``."""
+    import jax
+    from repro.distributed import serving_sharding
+    from repro.launch.mesh import make_test_mesh
+    x, w = xw
+    _, sw = _sparse_bf16(w, sparsity=0.5)
+    ctx = serving_sharding.serving_ctx(make_test_mesh(1, 1))
+    with ops.backend("interpret"):
+        bare = ops.linear(x, sw, out_dtype=jnp.float32)
+        with ops.kernel_mesh(ctx):
+            jaxpr = str(jax.make_jaxpr(
+                lambda x: ops.linear(x, sw, out_dtype=jnp.float32))(x))
+            wrapped = ops.linear(x, sw, out_dtype=jnp.float32)
+    assert "shard_map" in jaxpr and "'data'" in jaxpr
+    assert ops._SHARD is None
+    np.testing.assert_array_equal(np.asarray(wrapped), np.asarray(bare))

@@ -68,6 +68,17 @@ def test_sharded_pool_refreeze_rollback_roundtrip():
     assert rec["tail_len"] == [0, 0, 0, 0]
 
 
+def test_sharded_engine_pallas_kernels_parity():
+    """The Pallas kernels (interpret mode) under a 2x2 mesh serve the
+    unsharded engine's greedy tokens, with the pooled state and the
+    attention kernel's operands split over slots and KV heads."""
+    rec = run_worker("kernels")["kernels"]
+    assert rec["tokens_match"] and rec["n_tokens"] == 96, rec
+    assert rec["state_spans"] == [4], rec
+    assert rec["kernel_on_slots_and_heads"], rec
+    assert rec["decode_traces"] == 1, rec
+
+
 # ---------------------------------------------------------------------------
 # spec derivation units (no devices needed — FakeMesh like test_sharding)
 # ---------------------------------------------------------------------------

@@ -11,7 +11,11 @@ parent test process can stay single-device) and prints one JSON record:
 * ``spec`` — the same parity bar for the draft–verify engine under the
   4x2 mesh (one jitted verify panel + on-device rollback, sharded);
 * ``pool`` — a refreeze + rollback round-trip on mesh-sharded pool state
-  vs the same transitions unsharded (observable state equality).
+  vs the same transitions unsharded (observable state equality);
+* ``kernels`` — the Pallas kernels (interpret mode) on 50% sparse weights
+  and a paged pool with chunked prefill and overlapped ticks: a 2x2-mesh
+  engine vs the unsharded engine, with the pooled state and the attention
+  kernel's operands split over all four devices.
 """
 import os
 import sys
@@ -167,6 +171,40 @@ def run_pool(cfg, params, toks):
             "tail_len": va["tail_len"]}
 
 
+def run_kernels(cfg, params, toks):
+    """Greedy tokens of the 2x2-mesh engine vs one device, both through
+    the Pallas kernels, on the path ``chip_smoke.py --four-chips`` runs."""
+    from repro.distributed import NULL_CTX
+    from repro.distributed.convert_plan import convert_concrete
+    from repro.kernels import ops
+    from repro.launch.mesh import make_test_mesh
+    sparse = convert_concrete(params, lm.model_specs(cfg), cfg, NULL_CTX)
+    prompts = [np.asarray(toks[i][:10 + 9 * i]) for i in range(4)]
+
+    def serve(mesh):
+        eng = ContinuousEngine(sparse, cfg, slots=4, max_tokens=96, bs=16,
+                               prefill_chunk=16, paged=True, overlap=True,
+                               mesh=mesh)
+        rids = [eng.submit(p, SamplingParams(max_new_tokens=24))
+                for p in prompts]
+        res = eng.run()
+        return eng, [list(res[r].token_ids) for r in rids]
+
+    with ops.backend("interpret"):
+        _, base = serve(None)
+        eng, toks_mesh = serve(make_test_mesh(2, 2))
+        fn, args = eng.entry_points()["decode"]
+        jaxpr = str(jax.make_jaxpr(fn)(*args))
+    spans = sorted({len(leaf.sharding.device_set)
+                    for leaf in jax.tree_util.tree_leaves(eng.state)})
+    return {"tokens_match": toks_mesh == base,
+            "n_tokens": sum(map(len, base)),
+            "state_spans": spans,
+            "decode_traces": eng.trace_counts()["decode"],
+            "kernel_on_slots_and_heads": "PartitionSpec('data', 'model', None, None)"
+            in jaxpr}
+
+
 def main():
     cfg, params, toks = _setup()
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
@@ -177,6 +215,8 @@ def main():
         rec["spec"] = run_spec(cfg, params, toks)
     if which in ("all", "pool"):
         rec["pool"] = run_pool(cfg, params, toks)
+    if which in ("all", "kernels"):
+        rec["kernels"] = run_kernels(cfg, params, toks)
     print(json.dumps(rec))
 
 
