@@ -13,7 +13,11 @@ Components:
 * :class:`~repro.obs.metrics.MetricsRegistry` — counters / gauges /
   exact-percentile latency histograms, ``registry.snapshot()`` dict.
 * :class:`~repro.obs.trace.TraceSink` — request-lifecycle spans in the
-  Chrome trace-event format (``chrome://tracing`` / Perfetto).
+  Chrome trace-event format (``chrome://tracing`` / Perfetto), on the
+  host clock.
+* :mod:`~repro.obs.spans` — the names of the engine's program spans,
+  which the engine records through ``jax.profiler.TraceAnnotation`` on
+  the profiler's clock, beside the device's operations.
 * :class:`~repro.obs.prometheus.MetricsServer` — background-thread
   ``/metrics`` scrape endpoint; :func:`~repro.obs.prometheus.render`
   for the text exposition itself.
@@ -34,6 +38,7 @@ from .metrics import (
     percentile_summary,
 )
 from .prometheus import CONTENT_TYPE, MetricsServer, render
+from . import spans
 from .trace import PID_ENGINE, PID_REQUESTS, TraceSink
 
 __all__ = [
@@ -52,6 +57,7 @@ __all__ = [
     "CONTENT_TYPE",
     "PID_ENGINE",
     "PID_REQUESTS",
+    "spans",
 ]
 
 
@@ -116,6 +122,14 @@ class Observability:
             "repro_prefill_time_seconds", "admission -> first token")
         self._e2e_h = r.histogram(
             "repro_e2e_seconds", "submit -> finish")
+        self._refreezes = r.counter(
+            "repro_refreezes_total", "tail folds dispatched")
+        self._fold_full = r.counter(
+            "repro_refreeze_slots_total", "slot rows per fold: tails that "
+            "were full and folded (full), rows the refreeze program "
+            "compressed (compressed)", tail="full")
+        self._fold_compressed = r.counter(
+            "repro_refreeze_slots_total", tail="compressed")
         # live tok/s: rolling median of per-tick committed/duration
         self.tok_rate = RollingWindow(64)
         # delta-sync state for external monotonic counters
@@ -125,7 +139,7 @@ class Observability:
         if self.trace is not None:
             self.trace.process_name(PID_ENGINE, "engine")
             self.trace.thread_name(PID_ENGINE, 0, "ticks")
-            self.trace.thread_name(PID_ENGINE, 1, "device steps")
+            self.trace.thread_name(PID_ENGINE, 1, "host dispatch")
             self.trace.process_name(PID_REQUESTS, "requests")
 
     # -- delta sync -----------------------------------------------------
@@ -205,34 +219,47 @@ class Observability:
 
     def prefill_chunk(self, rid: int, slot: int, start: float, dur: float,
                       n_tokens: int, final: bool) -> None:
+        """One prefill chunk's host span: dispatch wall time, and for the
+        final chunk the first-token sync as well.  Not device time (the
+        device's is in a profiler trace, under ``jit__prefill``)."""
         self.registry.histogram(
             "repro_prefill_chunk_seconds",
-            "one chunked-prefill host dispatch (the final chunk includes "
-            "the first-token sync)").observe(dur)
+            "host time of one chunked-prefill dispatch (the final chunk "
+            "includes the first-token sync); not device time").observe(dur)
         if self.trace is not None:
             self.trace.complete("prefill_chunk", start, dur, pid=PID_ENGINE,
-                                tid=1, cat="device",
+                                tid=1, cat="dispatch",
                                 args={"rid": rid, "slot": slot,
                                       "tokens": n_tokens, "final": final})
 
     def decode_tick(self, start: float, dur: float, n_slots: int,
                     spec: bool, overlapped: bool = False) -> None:
-        """One decode/verify window's device span.  Under the overlapped
-        engine the span runs dispatch -> the one-tick-DELAYED sync, so it
-        reflects true pipelined wall clock (host work only shows where it
-        failed to hide behind the device); the metric keeps its mode label
-        and the trace event gains an ``overlapped`` arg."""
+        """One decode/verify window's host span, from its dispatch to its
+        token sync on the host clock: not device time (the device's is in
+        a profiler trace, under ``jit__decode_chain`` and friends).  Under
+        the overlapped engine the sync is one tick DELAYED, so the span
+        also holds the next tick's host work; the metric keeps its mode
+        label and the trace event gains an ``overlapped`` arg."""
         mode = "spec" if spec else "plain"
         self.registry.histogram(
             "repro_decode_tick_seconds",
-            "decode dispatch through the token sync", mode=mode).observe(dur)
+            "host time from a decode/verify dispatch to its token sync; "
+            "not device time", mode=mode).observe(dur)
         if self.trace is not None:
             args: Dict[str, Any] = {"slots": n_slots}
             if overlapped:
                 args["overlapped"] = True
             self.trace.complete("verify" if spec else "decode", start, dur,
-                                pid=PID_ENGINE, tid=1, cat="device",
+                                pid=PID_ENGINE, tid=1, cat="dispatch",
                                 args=args)
+
+    def refreeze(self, full_slots: int, compressed_slots: int) -> None:
+        """One fold dispatched: ``full_slots`` tails were full and folded,
+        and the refreeze program compressed ``compressed_slots`` rows.
+        Their ratio is the fold's useful share of its compression work."""
+        self._refreezes.inc()
+        self._fold_full.inc(full_slots)
+        self._fold_compressed.inc(compressed_slots)
 
     def prefix_match(self, hit_blocks: int, lookup_blocks: int) -> None:
         self.registry.counter(

@@ -8,9 +8,10 @@ https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU
 
 Layout convention used by :class:`repro.obs.Observability`:
 
-* ``pid 0`` ("engine"): tick spans on ``tid 0``, device-step spans
-  (decode / verify / prefill-chunk) on ``tid 1``, counter tracks and
-  fault/snapshot instants on ``tid 0``.
+* ``pid 0`` ("engine"): tick spans on ``tid 0``, host-dispatch spans
+  (decode / verify / prefill-chunk: dispatch to token sync on the host
+  clock, not device time) on ``tid 1``, counter tracks and fault/snapshot
+  instants on ``tid 0``.
 * ``pid 1`` ("requests"): one row per request id with ``queued`` /
   ``prefill`` / ``decode`` spans and ``submit`` / ``finish:<reason>``
   instants.
@@ -19,6 +20,10 @@ Timestamps are **seconds in** (whatever clock the engine's scheduler
 uses — ``time.monotonic`` in production, a fake in tests) and
 microseconds-on-the-page out, rebased to the first event so traces start
 at t=0.
+
+This is a second timeline: it cannot be lined up with a ``jax.profiler``
+trace.  The engine's spans on the profiler's clock, beside the device's
+operations, are :mod:`repro.obs.spans`.
 """
 from __future__ import annotations
 

@@ -388,6 +388,13 @@ class CachePool:
                 1, mode="drop")
         return out
 
+    def refreeze_rows(self, n_full: int) -> int:
+        """Slot rows one :meth:`refreeze` compresses when ``n_full`` tails
+        are full: every slot's, at static shape, however few are full.
+        The engine counts this per fold (``repro_refreeze_slots_total``),
+        so it changes whenever the transition does."""
+        return self.slots
+
     def assign_blocks(self, state: Dict[str, Any], slot: jax.Array,
                       ids: jax.Array, n: jax.Array) -> Dict[str, Any]:
         """Point a freshly-admitted slot's table row at ``n`` existing

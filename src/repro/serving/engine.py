@@ -26,17 +26,20 @@ broadcast lane in the legacy engine) — argmax is just the
 """
 from __future__ import annotations
 
+import functools
 from typing import (Any, Callable, Dict, Iterator, List, Optional, Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.sparse_kv import SparseKVCache, freeze_prefix
 from repro.distributed import NULL_CTX, serving_sharding
 from repro.kernels import ops
 from repro.models import lm
 from repro.models.attention import DenseKVCache
+from repro.obs import spans
 
 from . import sampling
 from .cache_pool import BlockAllocator, CachePool
@@ -329,6 +332,16 @@ class ContinuousEngine:
     discarded speculative draws happen strictly after the request's
     last committed draw.  :meth:`quiesce` drains the pipeline (snapshot
     paths call it implicitly).
+
+    **Program spans and names**: each tick opens the
+    ``jax.profiler.TraceAnnotation`` spans of :mod:`repro.obs.spans`
+    (``repro.tick`` holding ``repro.admit``, ``repro.refreeze``,
+    ``repro.prefill``, ``repro.decode``, ``repro.sync``,
+    ``repro.release``), recorded only under a profiler trace, on the
+    clock of the device's operations; every jitted step is a named
+    function, so its XLA program is ``jit_<name>`` (``jit__refreeze``,
+    ``jit__decode_chain``) on one device, under a mesh and under checkify
+    alike.
     """
 
     def __init__(self, params, cfg, ctx=NULL_CTX, slots: int = 4,
@@ -402,7 +415,9 @@ class ContinuousEngine:
             def _jit(fn, in_s, out_s):
                 # the Pallas kernels cannot be partitioned by XLA: they
                 # trace as shard_maps that keep the serving placement
-                # (kernels/ops.py)
+                # (kernels/ops.py).  wraps keeps fn's name, so the
+                # program compiles as jit_<fn name>, not jit_on_mesh
+                @functools.wraps(fn)
                 def on_mesh(*args):
                     with ops.kernel_mesh(ctx):
                         return fn(*args)
@@ -417,8 +432,10 @@ class ContinuousEngine:
             st_sh = tok_sh = vec_sh = rep = par_sh = None
 
             def _jit(fn, in_s, out_s):
-                checked = jax.jit(cache_pool_checkified_raw(fn))
+                checked = jax.jit(
+                    functools.wraps(fn)(cache_pool_checkified_raw(fn)))
 
+                @functools.wraps(fn)
                 def run(*args):
                     err, out = checked(*args)
                     err.throw()
@@ -460,32 +477,38 @@ class ContinuousEngine:
                 lanes["rng"], lane["rng"], s, axis=0)}
             return tok, logp, {**st, "sample": lanes}
 
+        # every jitted step is a named function: XLA names its program
+        # jit_<function name> (jit__decode_chain, jit__refreeze, ...), which
+        # is how a profiler trace tells the programs apart
+        def _refreeze(st, ids=None):
+            return self.pool.refreeze(st, new_ids=ids)
+
+        def _assign(st, s, ids, n):
+            return self.pool.assign_blocks(st, s, ids, n)
+
+        # a fresh function object, NOT sampling.set_lane itself: pjit's
+        # fastpath cache is keyed on the function, so jitting the shared
+        # module function would let other engines' pool geometries count
+        # against this engine's trace_counts()
+        def _set_lane(st, slot, t, k, p, key):
+            return sampling.set_lane(st, slot, t, k, p, key)
+
         self._decode = _jit(_decode, (par_sh, st_sh, tok_sh, vec_sh),
                             (vec_sh, vec_sh, st_sh))
         if paged:
             self._prefill_chunk = _jit(
                 _prefill, (par_sh, st_sh, rep, rep, rep, rep),
                 (rep, rep, st_sh))
-            self._refreeze = _jit(
-                lambda st, ids: self.pool.refreeze(st, new_ids=ids),
-                (st_sh, rep), st_sh)
-            self._assign = _jit(
-                lambda st, s, ids, n: self.pool.assign_blocks(st, s, ids, n),
-                (st_sh, rep, rep, rep), st_sh)
+            self._refreeze = _jit(_refreeze, (st_sh, rep), st_sh)
+            self._assign = _jit(_assign, (st_sh, rep, rep, rep), st_sh)
         else:
             self._prefill_chunk = _jit(
                 _prefill, (par_sh, st_sh, rep, rep, rep), (rep, rep, st_sh))
-            self._refreeze = _jit(self.pool.refreeze, (st_sh,), st_sh)
+            self._refreeze = _jit(_refreeze, (st_sh,), st_sh)
             self._assign = None
         self._release = _jit(self.pool.release, (st_sh, rep), st_sh)
-        # a fresh function object, NOT sampling.set_lane itself: pjit's
-        # fastpath cache is keyed on the function, so jitting the shared
-        # module function would let other engines' pool geometries count
-        # against this engine's trace_counts()
-        self._set_lane = _jit(
-            lambda st, slot, t, k, p, key:
-                sampling.set_lane(st, slot, t, k, p, key),
-            (st_sh, rep, rep, rep, rep, rep), st_sh)
+        self._set_lane = _jit(_set_lane, (st_sh, rep, rep, rep, rep, rep),
+                              st_sh)
 
         # speculative decoding: one jitted draft–verify step scores all
         # K+1 panel positions in a single forward over the pooled cache,
@@ -939,42 +962,47 @@ class ContinuousEngine:
         admitted this tick can never land in a slot whose release is still
         pending from an expiry — admission only sees fully-released slots.
         """
-        obs = self._obs
-        t_start = self.scheduler.clock() if obs is not None else 0.0
-        self._tick_no += 1
-        self._in_tick = True
-        self._tick_committed = 0
-        try:
-            return self._step_inner()
-        finally:
-            if self._faults is not None:
-                # double-release fault: push an already-freed slot through
-                # the release path again.  The flush must absorb it as a
-                # counted warning (and the device transition as a no-op).
-                cand = (list(self._pending_release)
-                        or [s for s in range(self.pool.slots)
-                            if s not in self.scheduler.active])
-                if cand and self._faults.take(DOUBLE_RELEASE, self._tick_no):
-                    self._pending_release.append(self._faults.choose(cand))
-            self._flush_releases()
-            self._in_tick = False
-            if obs is not None:
-                sch = self.scheduler
-                obs.tick(
-                    start=t_start, now=sch.clock(), tick_no=self._tick_no,
-                    committed=self._tick_committed,
-                    queue_depth=len(sch.queue), active=len(sch.active),
-                    slots=self.pool.slots, counters=self.fault_counters,
-                    free_blocks=(self._alloc.free_blocks()
-                                 if self._alloc is not None else None),
-                    n_phys=(self.pool.n_phys
-                            if self._alloc is not None else 0),
-                    evictions=(self._alloc.evictions
-                               if self._alloc is not None else 0),
-                    trie_blocks=(len(self._trie)
-                                 if self._trie is not None else 0),
-                    spec_hist=(self.spec_hist.tolist()
-                               if self._spec is not None else None))
+        with TraceAnnotation(spans.TICK):
+            obs = self._obs
+            t_start = self.scheduler.clock() if obs is not None else 0.0
+            self._tick_no += 1
+            self._in_tick = True
+            self._tick_committed = 0
+            try:
+                return self._step_inner()
+            finally:
+                faults = self._faults
+                if faults is not None:
+                    # double-release fault: push an already-freed slot
+                    # through the release path again.  The flush must absorb
+                    # it as a counted warning (and the device transition as
+                    # a no-op).
+                    cand = (list(self._pending_release)
+                            or [s for s in range(self.pool.slots)
+                                if s not in self.scheduler.active])
+                    if cand and faults.take(DOUBLE_RELEASE, self._tick_no):
+                        self._pending_release.append(faults.choose(cand))
+                if self._pending_release:
+                    with TraceAnnotation(spans.RELEASE):
+                        self._flush_releases()
+                self._in_tick = False
+                if obs is not None:
+                    sch = self.scheduler
+                    obs.tick(
+                        start=t_start, now=sch.clock(), tick_no=self._tick_no,
+                        committed=self._tick_committed,
+                        queue_depth=len(sch.queue), active=len(sch.active),
+                        slots=self.pool.slots, counters=self.fault_counters,
+                        free_blocks=(self._alloc.free_blocks()
+                                     if self._alloc is not None else None),
+                        n_phys=(self.pool.n_phys
+                                if self._alloc is not None else 0),
+                        evictions=(self._alloc.evictions
+                                   if self._alloc is not None else 0),
+                        trie_blocks=(len(self._trie)
+                                     if self._trie is not None else 0),
+                        spec_hist=(self.spec_hist.tolist()
+                                   if self._spec is not None else None))
 
     def _flush_releases(self) -> None:
         """Recycle every pending slot in one batched device release.
@@ -1066,19 +1094,10 @@ class ContinuousEngine:
             self._tail_len[req.slot] = 0
         return req
 
-    def _step_inner(self) -> List[RequestOutput]:
-        events: List[RequestOutput] = []
+    def _admit(self, now: float) -> None:
+        """Fill every free slot from the queue, writing each new request's
+        sampling lane into device state."""
         sch = self.scheduler
-        # deadline expiry, then the release flush, THEN admission: slots
-        # freed by a timeout (or by a between-tick cancel) are fully
-        # released before any new request can be admitted into them — a
-        # pending release must never fire on a slot a fresh tenant just
-        # claimed.
-        now = sch.clock()
-        self._expire_deadlines(now, events)
-        self._flush_releases()
-        # admission: fill every free slot from the queue, writing each new
-        # request's sampling lane into device state
         while sch.queue and sch.free_slots():
             if sch.queue[0].next_admit > now:
                 break                          # head backing off: FIFO waits
@@ -1092,6 +1111,20 @@ class ContinuousEngine:
                 jnp.float32(p.temperature), jnp.int32(p.top_k),
                 jnp.float32(p.top_p), sampling.request_key(p))
             self._slot_live[req.slot] = True
+
+    def _step_inner(self) -> List[RequestOutput]:
+        events: List[RequestOutput] = []
+        sch = self.scheduler
+        # deadline expiry, then the release flush, THEN admission: slots
+        # freed by a timeout (or by a between-tick cancel) are fully
+        # released before any new request can be admitted into them — a
+        # pending release must never fire on a slot a fresh tenant just
+        # claimed.
+        now = sch.clock()
+        with TraceAnnotation(spans.ADMIT):
+            self._expire_deadlines(now, events)
+            self._flush_releases()
+            self._admit(now)
 
         # cancellation-mid-prefill fault: kill a partially-prefilled
         # request between its chunks — its pages must come back (at tick
@@ -1140,25 +1173,28 @@ class ContinuousEngine:
             return self._overlap_decode_tick(slots, events)
         b = self.pool.slots
         t_dec = sch.clock() if self._obs is not None else 0.0
-        tokens = np.zeros((b, 1), np.int32)
-        mask = np.zeros((b,), bool)
-        for s in slots:
-            tokens[s, 0] = self._last_tok[s]
-            mask[s] = True
-        tok, logp, self.state = self._decode(
-            self.params, self.state, jnp.asarray(tokens), jnp.asarray(mask))
-        picked, logps = np.asarray(tok), np.asarray(logp)
-        if self._obs is not None:
-            # span covers dispatch through the np.asarray token sync — the
-            # tick's designated host<->device boundary
-            self._obs.decode_tick(t_dec, sch.clock() - t_dec, len(slots),
-                                  spec=False)
-        for s in slots:
-            if s not in sch.active:
-                continue      # cancelled mid-tick (reentrant callback):
-                              # the sampled token dies with the slot
-            self._tail_len[s] += 1
-            self._emit(s, [int(picked[s])], [float(logps[s])], events)
+        with TraceAnnotation(spans.DECODE):
+            tokens = np.zeros((b, 1), np.int32)
+            mask = np.zeros((b,), bool)
+            for s in slots:
+                tokens[s, 0] = self._last_tok[s]
+                mask[s] = True
+            tok, logp, self.state = self._decode(
+                self.params, self.state, jnp.asarray(tokens),
+                jnp.asarray(mask))
+        with TraceAnnotation(spans.SYNC):
+            picked, logps = np.asarray(tok), np.asarray(logp)
+            if self._obs is not None:
+                # span covers dispatch through the np.asarray token sync —
+                # the tick's designated host<->device boundary
+                self._obs.decode_tick(t_dec, sch.clock() - t_dec, len(slots),
+                                      spec=False)
+            for s in slots:
+                if s not in sch.active:
+                    continue      # cancelled mid-tick (reentrant callback):
+                                  # the sampled token dies with the slot
+                self._tail_len[s] += 1
+                self._emit(s, [int(picked[s])], [float(logps[s])], events)
         return events
 
     def _refreeze_tick(self, events: List[RequestOutput]) -> None:
@@ -1170,6 +1206,12 @@ class ContinuousEngine:
                 if self._tail_len[s] >= self.pool.tail]
         if not full:
             return
+        with TraceAnnotation(spans.REFREEZE):
+            self._fold(full, events)
+
+    def _fold(self, full: List[int], events: List[RequestOutput]) -> None:
+        """Dispatch one refreeze of the ``full`` slots' tails and count
+        it."""
         if self._alloc is not None:
             tb = self.pool.tail // self.pool.bs
             if (self._inflight is not None
@@ -1198,6 +1240,11 @@ class ContinuousEngine:
             self.state = self._refreeze(self.state)
         for s in full:
             self._tail_len[s] = 0
+        if self._obs is not None:
+            # host integers only: the full slots the host mirrors counted,
+            # and the rows the refreeze program compresses for them
+            self._obs.refreeze(len(full),
+                               self.pool.refreeze_rows(len(full)))
 
     def _prefill_tick(self, events: List[RequestOutput]) -> None:
         """One prefill chunk for the oldest request still owed prompt work.
@@ -1209,7 +1256,9 @@ class ContinuousEngine:
         """
         sch = self.scheduler
         req = sch.next_prefill()
-        if req is not None:
+        if req is None:
+            return
+        with TraceAnnotation(spans.PREFILL):
             t_pf = sch.clock() if self._obs is not None else 0.0
             off0 = req.prefill_done
             chunk = sch.prefill_chunk(req)
@@ -1275,61 +1324,62 @@ class ContinuousEngine:
         sch = self.scheduler
         b = self.pool.slots
         t_dec = sch.clock() if self._obs is not None else 0.0
-        rec = self._inflight
-        if rec is None:
-            # cold pipeline (first tick, or just drained): there is no
-            # device token to chain on, so dispatch through the regular
-            # decode entry from the host mirrors — same computation, and
-            # _decode_chain only ever sees jit-output `prev` operands
-            # (mixing host arrays in would key a second compile-cache
-            # entry and break the zero-retrace bar)
-            tokens = np.zeros((b, 1), np.int32)
-            mask = np.zeros((b,), bool)
-            for s in slots:
-                tokens[s, 0] = self._last_tok[s]
-                mask[s] = True
-            tok, logp, self.state = self._decode(
-                self.params, self.state, jnp.asarray(tokens),
-                jnp.asarray(mask))
-        else:
-            chained = set()
-            if rec["kind"] == "decode":
-                for s, rid in rec["slots"]:
-                    req = sch.active.get(s)
-                    if req is not None and req.rid == rid:
-                        chained.add(s)
-            broken = [s for s in slots if s not in chained]
-            if broken:
-                ov = np.zeros((b,), np.int32)
-                ovm = np.zeros((b,), bool)
-                for s in broken:
-                    ovm[s] = True
-                    ov[s] = self._last_tok[s]
-                dov, dovm = jnp.asarray(ov), jnp.asarray(ovm)
-            else:
-                # unbroken chain (the steady state): constant all-zero
-                # overrides, transferred once and reused
-                if self._ov_zero is None:
-                    self._ov_zero = (
-                        jnp.asarray(np.zeros((b,), np.int32)),
-                        jnp.asarray(np.zeros((b,), bool)))
-                dov, dovm = self._ov_zero
-            mkey = tuple(slots)
-            dmask = self._mask_cache.get(mkey)
-            if dmask is None:
-                if len(self._mask_cache) >= 64:
-                    self._mask_cache.clear()
+        with TraceAnnotation(spans.DECODE):
+            rec = self._inflight
+            if rec is None:
+                # cold pipeline (first tick, or just drained): there is no
+                # device token to chain on, so dispatch through the regular
+                # decode entry from the host mirrors — same computation, and
+                # _decode_chain only ever sees jit-output `prev` operands
+                # (mixing host arrays in would key a second compile-cache
+                # entry and break the zero-retrace bar)
+                tokens = np.zeros((b, 1), np.int32)
                 mask = np.zeros((b,), bool)
-                mask[list(slots)] = True
-                dmask = self._mask_cache[mkey] = jnp.asarray(mask)
-            tok, logp, self.state = self._decode_chain(
-                self.params, self.state, rec["tok"], dov, dovm, dmask)
-        for s in slots:
-            self._tail_len[s] += 1
-        new_rec = {"kind": "decode", "tok": tok, "logp": logp,
-                   "ncommit": None, "dlen": None,
-                   "slots": [(s, sch.active[s].rid) for s in slots],
-                   "t0": t_dec, "n_slots": len(slots)}
+                for s in slots:
+                    tokens[s, 0] = self._last_tok[s]
+                    mask[s] = True
+                tok, logp, self.state = self._decode(
+                    self.params, self.state, jnp.asarray(tokens),
+                    jnp.asarray(mask))
+            else:
+                chained = set()
+                if rec["kind"] == "decode":
+                    for s, rid in rec["slots"]:
+                        req = sch.active.get(s)
+                        if req is not None and req.rid == rid:
+                            chained.add(s)
+                broken = [s for s in slots if s not in chained]
+                if broken:
+                    ov = np.zeros((b,), np.int32)
+                    ovm = np.zeros((b,), bool)
+                    for s in broken:
+                        ovm[s] = True
+                        ov[s] = self._last_tok[s]
+                    dov, dovm = jnp.asarray(ov), jnp.asarray(ovm)
+                else:
+                    # unbroken chain (the steady state): constant all-zero
+                    # overrides, transferred once and reused
+                    if self._ov_zero is None:
+                        self._ov_zero = (
+                            jnp.asarray(np.zeros((b,), np.int32)),
+                            jnp.asarray(np.zeros((b,), bool)))
+                    dov, dovm = self._ov_zero
+                mkey = tuple(slots)
+                dmask = self._mask_cache.get(mkey)
+                if dmask is None:
+                    if len(self._mask_cache) >= 64:
+                        self._mask_cache.clear()
+                    mask = np.zeros((b,), bool)
+                    mask[list(slots)] = True
+                    dmask = self._mask_cache[mkey] = jnp.asarray(mask)
+                tok, logp, self.state = self._decode_chain(
+                    self.params, self.state, rec["tok"], dov, dovm, dmask)
+            for s in slots:
+                self._tail_len[s] += 1
+            new_rec = {"kind": "decode", "tok": tok, "logp": logp,
+                       "ncommit": None, "dlen": None,
+                       "slots": [(s, sch.active[s].rid) for s in slots],
+                       "t0": t_dec, "n_slots": len(slots)}
         # commit tick t-1 while tick t computes behind it
         self._sync_inflight(events)
         self._inflight = new_rec
@@ -1353,34 +1403,35 @@ class ContinuousEngine:
         rec, self._inflight = self._inflight, None
         if rec is None:
             return
-        sch = self.scheduler
-        jax.block_until_ready((rec["tok"], rec["logp"]))
-        picked = np.asarray(rec["tok"])
-        logps = np.asarray(rec["logp"])
-        ncs = (np.asarray(rec["ncommit"])
-               if rec["ncommit"] is not None else None)
-        if self._obs is not None:
-            # the decode/verify span under overlap runs dispatch ->
-            # delayed sync: true device wall-clock, host work included
-            # only where it failed to hide
-            now = sch.clock()
-            self._obs.decode_tick(rec["t0"], now - rec["t0"],
-                                  rec["n_slots"], spec=ncs is not None,
-                                  overlapped=True)
-        for s, rid in rec["slots"]:
-            req = sch.active.get(s)
-            if req is None or req.rid != rid:
-                continue          # died in flight: the window is discarded
-            if ncs is None:
-                self._emit(s, [int(picked[s])], [float(logps[s])], events)
-            else:
-                nc = int(ncs[s])
-                self._tail_len[s] += nc      # t0 + accepted stay appended
-                self.spec_hist[nc - 1] += 1  # nc - 1 = accepted drafts
-                if self._adaptive is not None:
-                    self._adaptive.update(s, int(rec["dlen"][s]), nc - 1)
-                self._emit(s, [int(t) for t in picked[s, :nc]],
-                           [float(l) for l in logps[s, :nc]], events)
+        with TraceAnnotation(spans.SYNC):
+            sch = self.scheduler
+            jax.block_until_ready((rec["tok"], rec["logp"]))
+            picked = np.asarray(rec["tok"])
+            logps = np.asarray(rec["logp"])
+            ncs = (np.asarray(rec["ncommit"])
+                   if rec["ncommit"] is not None else None)
+            if self._obs is not None:
+                # the decode/verify host span under overlap runs dispatch
+                # -> delayed sync on the host clock, the next tick's host
+                # work included; device time is in the profiler trace
+                now = sch.clock()
+                self._obs.decode_tick(rec["t0"], now - rec["t0"],
+                                      rec["n_slots"], spec=ncs is not None,
+                                      overlapped=True)
+            for s, rid in rec["slots"]:
+                req = sch.active.get(s)
+                if req is None or req.rid != rid:
+                    continue          # died in flight: the window is discarded
+                if ncs is None:
+                    self._emit(s, [int(picked[s])], [float(logps[s])], events)
+                else:
+                    nc = int(ncs[s])
+                    self._tail_len[s] += nc      # t0 + accepted stay appended
+                    self.spec_hist[nc - 1] += 1  # nc - 1 = accepted drafts
+                    if self._adaptive is not None:
+                        self._adaptive.update(s, int(rec["dlen"][s]), nc - 1)
+                    self._emit(s, [int(t) for t in picked[s, :nc]],
+                               [float(l) for l in logps[s, :nc]], events)
 
     def _spec_tick(self, slots: List[int],
                    events: List[RequestOutput]) -> List[RequestOutput]:
@@ -1396,58 +1447,60 @@ class ContinuousEngine:
         """
         sch = self.scheduler
         t_dec = sch.clock() if self._obs is not None else 0.0
-        b, k = self.pool.slots, self._spec.k
-        # degraded mode: under queue pressure drop the draft window to 0 —
-        # every verify tick commits exactly one token, shrinking per-tick
-        # latency so live slots finish (and free) sooner.  Host data only:
-        # the [slots, K+1] panel shape never changes, so no retrace.
-        degraded = (self._degrade_queue > 0
-                    and len(sch.queue) >= self._degrade_queue)
-        if degraded:
-            self.fault_counters["degraded_ticks"] += 1
-        tokens = np.zeros((b, k + 1), np.int32)
-        mask = np.zeros((b,), bool)
-        dlen = np.zeros((b,), np.int32)
-        for s in slots:
-            req = sch.active[s]
-            tokens[s, 0] = self._last_tok[s]
-            mask[s] = True
-            room = self.pool.tail - 1 - int(self._tail_len[s])
-            cap = 0 if degraded else min(k, room)
-            if self._adaptive is not None and not degraded:
-                # per-slot adaptive K: a slot whose drafts keep getting
-                # rejected speculates less (host-side data only — the
-                # [slots, K+1] panel shape, and hence the trace, is fixed)
-                cap = min(cap, self._adaptive.draft_len(s))
-            if cap > 0:
-                try:
-                    if (self._faults is not None
-                            and self._faults.take(DRAFTER_ERROR,
-                                                  self._tick_no)):
-                        self._faults.raise_fault(DRAFTER_ERROR)
-                    drafts = self.drafter.propose(
-                        req.prompt + req.generated, cap)
-                except Exception:
-                    # a crashing drafter degrades its slot to a draftless
-                    # tick (one committed token) — never the engine
-                    self.fault_counters["drafter_error"] += 1
-                    drafts = []
-                dlen[s] = len(drafts)
-                tokens[s, 1:1 + len(drafts)] = drafts
-        slot_rids = [(s, sch.active[s].rid) for s in slots]
-        tok, logp, ncommit, self.state = self._verify(
-            self.params, self.state, jnp.asarray(tokens),
-            jnp.asarray(mask), jnp.asarray(dlen))
-        # cancellation-mid-spec-window fault: the victim's drafts were
-        # built into the panel and verified, but the window has not
-        # committed — the verified tokens must be discarded with the slot
-        if self._faults is not None:
-            alive = [s for s in slots if s in sch.active]
-            if alive and self._faults.take(CANCEL_SPEC, self._tick_no):
-                out = self._cancel_inner(
-                    sch.active[self._faults.choose(alive)].rid)
-                if out is not None:
-                    events.append(out)
+        with TraceAnnotation(spans.DECODE):
+            b, k = self.pool.slots, self._spec.k
+            # degraded mode: under queue pressure drop the draft window to
+            # 0 — every verify tick commits exactly one token, shrinking
+            # per-tick latency so live slots finish (and free) sooner.  Host
+            # data only: the [slots, K+1] panel shape never changes, so no
+            # retrace.
+            degraded = (self._degrade_queue > 0
+                        and len(sch.queue) >= self._degrade_queue)
+            if degraded:
+                self.fault_counters["degraded_ticks"] += 1
+            tokens = np.zeros((b, k + 1), np.int32)
+            mask = np.zeros((b,), bool)
+            dlen = np.zeros((b,), np.int32)
+            for s in slots:
+                req = sch.active[s]
+                tokens[s, 0] = self._last_tok[s]
+                mask[s] = True
+                room = self.pool.tail - 1 - int(self._tail_len[s])
+                cap = 0 if degraded else min(k, room)
+                if self._adaptive is not None and not degraded:
+                    # per-slot adaptive K: a slot whose drafts keep getting
+                    # rejected speculates less (host-side data only — the
+                    # [slots, K+1] panel shape, and hence the trace, is fixed)
+                    cap = min(cap, self._adaptive.draft_len(s))
+                if cap > 0:
+                    try:
+                        if (self._faults is not None
+                                and self._faults.take(DRAFTER_ERROR,
+                                                      self._tick_no)):
+                            self._faults.raise_fault(DRAFTER_ERROR)
+                        drafts = self.drafter.propose(
+                            req.prompt + req.generated, cap)
+                    except Exception:
+                        # a crashing drafter degrades its slot to a draftless
+                        # tick (one committed token) — never the engine
+                        self.fault_counters["drafter_error"] += 1
+                        drafts = []
+                    dlen[s] = len(drafts)
+                    tokens[s, 1:1 + len(drafts)] = drafts
+            slot_rids = [(s, sch.active[s].rid) for s in slots]
+            tok, logp, ncommit, self.state = self._verify(
+                self.params, self.state, jnp.asarray(tokens),
+                jnp.asarray(mask), jnp.asarray(dlen))
+            # cancellation-mid-spec-window fault: the victim's drafts were
+            # built into the panel and verified, but the window has not
+            # committed — the verified tokens must be discarded with the slot
+            if self._faults is not None:
+                alive = [s for s in slots if s in sch.active]
+                if alive and self._faults.take(CANCEL_SPEC, self._tick_no):
+                    out = self._cancel_inner(
+                        sch.active[self._faults.choose(alive)].rid)
+                    if out is not None:
+                        events.append(out)
         if self.overlap:
             # shallow pipeline: the window was dispatched, not synced — it
             # commits at the NEXT tick's _sync_inflight (after that tick's
@@ -1458,23 +1511,24 @@ class ContinuousEngine:
                               "slots": slot_rids, "t0": t_dec,
                               "n_slots": len(slots)}
             return events
-        picked, logps = np.asarray(tok), np.asarray(logp)
-        ncs = np.asarray(ncommit)
-        if self._obs is not None:
-            # draft + verify dispatch through the window sync
-            self._obs.decode_tick(t_dec, sch.clock() - t_dec, len(slots),
-                                  spec=True)
-        for s in slots:
-            if s not in sch.active:
-                continue      # cancelled inside the window: its verified
-                              # tokens are never committed
-            nc = int(ncs[s])
-            self._tail_len[s] += nc          # t0 + accepted stay appended
-            self.spec_hist[nc - 1] += 1      # nc - 1 = accepted drafts
-            if self._adaptive is not None:
-                self._adaptive.update(s, int(dlen[s]), nc - 1)
-            self._emit(s, [int(t) for t in picked[s, :nc]],
-                       [float(l) for l in logps[s, :nc]], events)
+        with TraceAnnotation(spans.SYNC):
+            picked, logps = np.asarray(tok), np.asarray(logp)
+            ncs = np.asarray(ncommit)
+            if self._obs is not None:
+                # draft + verify dispatch through the window sync
+                self._obs.decode_tick(t_dec, sch.clock() - t_dec, len(slots),
+                                      spec=True)
+            for s in slots:
+                if s not in sch.active:
+                    continue      # cancelled inside the window: its verified
+                                  # tokens are never committed
+                nc = int(ncs[s])
+                self._tail_len[s] += nc          # t0 + accepted stay appended
+                self.spec_hist[nc - 1] += 1      # nc - 1 = accepted drafts
+                if self._adaptive is not None:
+                    self._adaptive.update(s, int(dlen[s]), nc - 1)
+                self._emit(s, [int(t) for t in picked[s, :nc]],
+                           [float(l) for l in logps[s, :nc]], events)
         return events
 
     def _emit(self, slot: int, toks: List[int], logprobs: List[float],
