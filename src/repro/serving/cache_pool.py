@@ -287,11 +287,17 @@ class CachePool:
                  new_ids: Optional[jax.Array] = None) -> Dict[str, Any]:
         """Fold every full tail into its slot's next free prefix blocks.
 
-        In-place at static shapes: compress all slots' tails at the pool
-        capacity, scatter each full slot's new blocks at its own
-        ``prefix_blocks`` offset, select per slot.  Slots whose tail is not
-        full come back bit-identical.  The caller must ensure no full slot
-        overflows ``max_blocks`` (see ``Scheduler`` admission).
+        In-place at static shapes: the full slots are picked on device
+        from the ``tail_len >= tail`` mask, and a loop whose trip count is
+        their number compresses one slot's tail per iteration (all
+        periods, at the pool capacity) and writes its blocks at that
+        slot's own ``prefix_blocks`` offset.  Only full rows are
+        compressed: thresholds are per (row, block) and packing per row,
+        so a slot's blocks are the same bytes whichever slots fold with
+        it, and one compiled program covers every count of full slots.
+        Slots whose tail is not full come back bit-identical.  The caller
+        must ensure no full slot overflows ``max_blocks`` (see
+        ``Scheduler`` admission).
 
         Paged pool: ``new_ids`` int32 ``[slots, tail // bs]`` must carry a
         FRESH physical block id per (full slot, tail block) — the host
@@ -306,94 +312,89 @@ class CachePool:
         t, tb = self.tail, self.tail // self.bs
         full = state["tail_len"] >= t                           # [B]
         pb = state["prefix_blocks"]
+        # the full slots in slot order; entries past n_full are never read
+        rows = jnp.nonzero(full, size=self.slots, fill_value=0)[0]
+        n_full = jnp.sum(full, dtype=jnp.int32)
+        carry = {"layers": {name: {k: leaf["kv"][k] for k in ARENA_KEYS}
+                            for name, leaf in state["layers"].items()}}
         if self.paged:
             if new_ids is None:
                 raise ValueError("paged refreeze needs fresh ids")
-            # masked flat scatter: non-full slots' rows are re-pointed at
-            # id == n_phys, which every mode="drop" scatter discards
             ids = jnp.asarray(new_ids, jnp.int32)               # [B, tb]
-            drop_ids = jnp.where(full[:, None], ids,
-                                 self.n_phys).reshape(-1)       # [B*tb]
             if self.checkify:
                 # sanitized mode: ids for full slots must be in-arena AND
                 # unreferenced (fresh pages are what guarantees
-                # copy-on-write); id == n_phys sentinel rows are the
-                # intentional drops.  Guarded so the default path traces
-                # zero extra eqns.
-                live_id = drop_ids < self.n_phys
-                self._check(jnp.all(jnp.where(live_id, drop_ids >= 0,
-                                              True)),
+                # copy-on-write); non-full rows are ignored.  Guarded so
+                # the default path traces zero extra eqns.
+                live_id = full[:, None] & (ids < self.n_phys)
+                self._check(jnp.all(jnp.where(live_id, ids >= 0, True)),
                             "refreeze: fresh id out of arena range")
-                rc = jnp.take(state["refcount"], drop_ids, mode="clip")
+                rc = jnp.take(state["refcount"], ids, mode="clip")
                 self._check(jnp.all(jnp.where(live_id, rc == 0, True)),
                             "refreeze: fresh id already referenced "
                             "(copy-on-write violation)")
-        new_layers = {}
-        for name, leaf in state["layers"].items():
-            kv = leaf["kv"]
-            p_, b_, hkv, _, hd = kv["k_tail"].shape
-            flat = lambda a: a.reshape(p_ * b_, hkv, t, hd)
-            k_bm, k_vl, v_bm, v_vl = freeze_chunk_blocks(
-                flat(kv["k_tail"]), flat(kv["v_tail"]),
-                cfg.kv_k_sparsity, cfg.kv_v_sparsity,
-                self.bs, self.cap_k, self.cap_v)
-
-            if self.paged:
-                def write(dst, upd):
-                    # [P*B, Hkv, tb, X] -> [P, B*tb, Hkv, X] rows, scattered
-                    # at the fresh ids on the arena's physical-block axis
-                    u = upd.reshape(p_, b_, hkv, tb, -1)
-                    u = u.transpose(0, 1, 3, 2, 4).reshape(
-                        p_, b_ * tb, hkv, -1)
-                    return dst.at[:, drop_ids].set(
-                        u.astype(dst.dtype), mode="drop")
-            else:
-                unflat = lambda a: a.reshape((p_, b_) + a.shape[1:])
-
-                def write(dst, upd):
-                    # per-slot offset scatter over the block axis
-                    upd = unflat(upd)
-                    out = jax.vmap(
-                        lambda db, ub, off: jax.lax.dynamic_update_slice(
-                            db, ub.astype(db.dtype), (0, 0, off, 0)),
-                        in_axes=(1, 1, 0), out_axes=1)(dst, upd, pb)
-                    sel = full.reshape((1, b_) + (1,) * (dst.ndim - 2))
-                    return jnp.where(sel, out, dst)
-
-            new_layers[name] = {"kv": {
-                **kv,
-                "k_bitmap": write(kv["k_bitmap"], k_bm),
-                "k_values": write(kv["k_values"], k_vl),
-                "v_bitmap": write(kv["v_bitmap"], v_bm),
-                "v_values": write(kv["v_values"], v_vl),
-            }}
-        grow = jnp.where(full, tb, 0).astype(jnp.int32)
+            carry["table"] = state["table"]
+            carry["refcount"] = state["refcount"]
         if self.checkify:
             self._check(jnp.all(jnp.where(full, pb + tb <= self.max_blocks,
                                           True)),
                         "refreeze: full slot would overflow max_blocks")
-        out = {**state, "layers": new_layers,
-               "prefix_blocks": pb + grow,
+
+        def fold(i, c):
+            s = rows[i]
+            off = pb[s]
+            tail = lambda a: jax.lax.dynamic_index_in_dim(
+                a, s, axis=1, keepdims=False)                   # [P,Hkv,T,D]
+            if self.paged:
+                # an id past the arena is dropped by every scatter below
+                slot_ids = ids[s]                               # [tb]
+                # [P, Hkv, tb, X] -> [P, tb, Hkv, X] rows at the fresh ids
+                # on the arena's physical-block axis
+                write = lambda dst, upd: dst.at[:, slot_ids].set(
+                    upd.transpose(0, 2, 1, 3).astype(dst.dtype), mode="drop")
+            else:
+                write = lambda dst, upd: jax.lax.dynamic_update_slice(
+                    dst, upd[:, None].astype(dst.dtype), (0, s, 0, off, 0))
+            new_layers = {}
+            for name, comp in c["layers"].items():
+                kv = state["layers"][name]["kv"]
+                packed = freeze_chunk_blocks(
+                    tail(kv["k_tail"]), tail(kv["v_tail"]),
+                    cfg.kv_k_sparsity, cfg.kv_v_sparsity,
+                    self.bs, self.cap_k, self.cap_v)            # [P,Hkv,tb,X]
+                new_layers[name] = {k: write(comp[k], upd)
+                                    for k, upd in zip(ARENA_KEYS, packed)}
+            out = {"layers": new_layers}
+            if self.paged:
+                # the table row grows by tb entries at the slot's own
+                # offset (ids clipped in range: table entries are consumed
+                # by kernel index maps, so even dead ones must address
+                # real storage)
+                out["table"] = jax.lax.dynamic_update_slice(
+                    c["table"], jnp.clip(slot_ids, 0, self.n_phys - 1)[None],
+                    (s, off))
+                out["refcount"] = c["refcount"].at[slot_ids].add(
+                    1, mode="drop")
+            return out
+
+        carry = jax.lax.fori_loop(0, n_full, fold, carry)
+        out = {**state,
+               "layers": {name: {"kv": {**leaf["kv"],
+                                        **carry["layers"][name]}}
+                          for name, leaf in state["layers"].items()},
+               "prefix_blocks": pb + jnp.where(full, tb, 0).astype(jnp.int32),
                "tail_len": jnp.where(full, 0, state["tail_len"])}
         if self.paged:
-            # table rows grow by tb entries at each full slot's own offset
-            # (ids clipped in range: table entries are consumed by kernel
-            # index maps, so even dead ones must address real storage)
-            row_ids = jnp.clip(ids, 0, self.n_phys - 1)
-            grown = jax.vmap(
-                lambda row, idr, off: jax.lax.dynamic_update_slice(
-                    row, idr, (off,)))(state["table"], row_ids, pb)
-            out["table"] = jnp.where(full[:, None], grown, state["table"])
-            out["refcount"] = state["refcount"].at[drop_ids].add(
-                1, mode="drop")
+            out["table"] = carry["table"]
+            out["refcount"] = carry["refcount"]
         return out
 
     def refreeze_rows(self, n_full: int) -> int:
         """Slot rows one :meth:`refreeze` compresses when ``n_full`` tails
-        are full: every slot's, at static shape, however few are full.
-        The engine counts this per fold (``repro_refreeze_slots_total``),
-        so it changes whenever the transition does."""
-        return self.slots
+        are full: exactly those.  The engine counts this per fold
+        (``repro_refreeze_slots_total``), so it changes whenever the
+        transition does."""
+        return n_full
 
     def assign_blocks(self, state: Dict[str, Any], slot: jax.Array,
                       ids: jax.Array, n: jax.Array) -> Dict[str, Any]:

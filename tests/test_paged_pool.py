@@ -439,6 +439,192 @@ def test_checkify_off_traces_no_check_eqns():
 
 
 # ---------------------------------------------------------------------------
+# refreeze: only the full rows are compressed, bit for bit the all-rows fold
+# ---------------------------------------------------------------------------
+
+def _all_rows_refreeze(pool, state, new_ids=None):
+    """The fold as it was before it looped over the full slots: compress
+    every slot's tail, then keep the full rows by masked scatters.  Kept
+    here as the oracle the per-slot fold must match bit for bit."""
+    cfg = pool.cfg
+    t, tb = pool.tail, pool.tail // pool.bs
+    full = state["tail_len"] >= t
+    pb = state["prefix_blocks"]
+    if pool.paged:
+        ids = jnp.asarray(new_ids, jnp.int32)
+        drop_ids = jnp.where(full[:, None], ids, pool.n_phys).reshape(-1)
+    new_layers = {}
+    for name, leaf in state["layers"].items():
+        kv = leaf["kv"]
+        p_, b_, hkv, _, hd = kv["k_tail"].shape
+        flat = lambda a: a.reshape(p_ * b_, hkv, t, hd)
+        k_bm, k_vl, v_bm, v_vl = freeze_chunk_blocks(
+            flat(kv["k_tail"]), flat(kv["v_tail"]),
+            cfg.kv_k_sparsity, cfg.kv_v_sparsity,
+            pool.bs, pool.cap_k, pool.cap_v)
+        if pool.paged:
+            def write(dst, upd):
+                u = upd.reshape(p_, b_, hkv, tb, -1)
+                u = u.transpose(0, 1, 3, 2, 4).reshape(p_, b_ * tb, hkv, -1)
+                return dst.at[:, drop_ids].set(u.astype(dst.dtype),
+                                               mode="drop")
+        else:
+            def write(dst, upd):
+                upd = upd.reshape((p_, b_) + upd.shape[1:])
+                out = jax.vmap(
+                    lambda db, ub, off: jax.lax.dynamic_update_slice(
+                        db, ub.astype(db.dtype), (0, 0, off, 0)),
+                    in_axes=(1, 1, 0), out_axes=1)(dst, upd, pb)
+                sel = full.reshape((1, b_) + (1,) * (dst.ndim - 2))
+                return jnp.where(sel, out, dst)
+        new_layers[name] = {"kv": {
+            **kv,
+            "k_bitmap": write(kv["k_bitmap"], k_bm),
+            "k_values": write(kv["k_values"], k_vl),
+            "v_bitmap": write(kv["v_bitmap"], v_bm),
+            "v_values": write(kv["v_values"], v_vl),
+        }}
+    out = {**state, "layers": new_layers,
+           "prefix_blocks": pb + jnp.where(full, tb, 0).astype(jnp.int32),
+           "tail_len": jnp.where(full, 0, state["tail_len"])}
+    if pool.paged:
+        row_ids = jnp.clip(ids, 0, pool.n_phys - 1)
+        grown = jax.vmap(
+            lambda row, idr, off: jax.lax.dynamic_update_slice(
+                row, idr, (off,)))(state["table"], row_ids, pb)
+        out["table"] = jnp.where(full[:, None], grown, state["table"])
+        out["refcount"] = state["refcount"].at[drop_ids].add(1, mode="drop")
+    return out
+
+
+def _fold_pool(paged, checkify):
+    """A sparse pool whose V capacity truncates (both packing paths run):
+    4 slots, 2-block tails, an arena with room for every slot's prefix."""
+    cfg = get_config("qwen3-0.6b").reduced()
+    cfg = dataclasses.replace(cfg, kv_k_sparsity=0.3, kv_v_sparsity=0.5,
+                              kv_tail=32)
+    return CachePool.build(cfg, slots=4, max_tokens=128, bs=16,
+                           capacity_slack=1.0, paged=paged,
+                           checkify=checkify)
+
+
+def _fold_case(pool, full_slots, seed=0):
+    """A mid-serving state: random tails and compressed storage, slots at
+    different prefix lengths, ``full_slots`` with a full ring and the rest
+    part-way; for a paged pool the live table entries hold referenced
+    pages and the fresh ids point past them (non-full rows carry ids
+    that are live, which the fold must ignore)."""
+    rng = np.random.default_rng(seed)
+    b, tb = pool.slots, pool.tail // pool.bs
+    state = pool.init_state()
+    layers = {}
+    for name, leaf in state["layers"].items():
+        kv = {}
+        for k, a in leaf["kv"].items():
+            if a.dtype == jnp.uint32:
+                kv[k] = jnp.asarray(rng.integers(0, 2**32, a.shape,
+                                                 dtype=np.uint32))
+            else:
+                kv[k] = jnp.asarray(rng.normal(size=a.shape)).astype(a.dtype)
+        layers[name] = {"kv": kv}
+    pb = np.array([0, 1, 3, 2], np.int32)[:b]
+    tail_len = np.array([5, 17, 1, 30], np.int32)[:b]
+    tail_len[list(full_slots)] = pool.tail
+    state = {**state, "layers": layers,
+             "prefix_blocks": jnp.asarray(pb),
+             "tail_len": jnp.asarray(tail_len),
+             "pos": jnp.asarray(pb * pool.bs + tail_len)}
+    if not pool.paged:
+        return state, None
+    table = np.zeros((b, pool.max_blocks), np.int32)
+    refcount = np.zeros(pool.n_phys, np.int32)
+    nxt = 0
+    for s in range(b):
+        table[s, :pb[s]] = np.arange(nxt, nxt + pb[s])
+        refcount[nxt:nxt + pb[s]] = 1
+        nxt += pb[s]
+    ids = np.zeros((b, tb), np.int32)          # non-full: a live page
+    for s in full_slots:
+        ids[s] = np.arange(nxt, nxt + tb)
+        nxt += tb
+    state = {**state, "table": jnp.asarray(table),
+             "refcount": jnp.asarray(refcount)}
+    return state, jnp.asarray(ids)
+
+
+@pytest.fixture(scope="module")
+def folds():
+    """(pool, per-slot fold, all-rows oracle) for a pool kind, jitted once
+    per kind for the module."""
+    made = {}
+
+    def get(paged, checkify):
+        if (paged, checkify) not in made:
+            pool = _fold_pool(paged, checkify)
+            new = (_checked(pool.refreeze) if checkify
+                   else jax.jit(pool.refreeze))
+            oracle = jax.jit(lambda st, ids=None: _all_rows_refreeze(
+                pool, st, ids))
+            made[paged, checkify] = (pool, new, oracle)
+        return made[paged, checkify]
+    return get
+
+
+@pytest.mark.parametrize("full_slots", [(), (2,), (0, 3), (0, 1, 2, 3)],
+                         ids=["none", "one", "several", "all"])
+@pytest.mark.parametrize("checkify", [False, True],
+                         ids=["plain", "checkify"])
+@pytest.mark.parametrize("paged", [False, True], ids=["flat", "paged"])
+def test_refreeze_matches_all_rows_fold_bit_for_bit(folds, paged, checkify,
+                                                    full_slots):
+    """Compressing only the full slots' tails gives the state the all-rows
+    fold gives, every leaf bit for bit: a slot's blocks do not depend on
+    the slots folded beside it, and the rest of the state is untouched."""
+    pool, new, oracle = folds(paged, checkify)
+    state, ids = _fold_case(pool, full_slots)
+    args = (state, ids) if paged else (state,)
+    got, want = dict(new(*args)), oracle(*args)
+    leaves = lambda t: jax.tree_util.tree_flatten_with_path(t)[0]
+    assert len(leaves(got)) == len(leaves(want))
+    for (path, g), (_, w) in zip(leaves(got), leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape, path
+        np.testing.assert_array_equal(
+            np.asarray(g).view(np.uint8), np.asarray(w).view(np.uint8),
+            err_msg=jax.tree_util.keystr(path))
+    tb = pool.tail // pool.bs
+    grew = np.asarray(got["prefix_blocks"]) - np.asarray(
+        state["prefix_blocks"])
+    assert grew.tolist() == [tb if s in full_slots else 0
+                             for s in range(pool.slots)]
+    assert pool.refreeze_rows(len(full_slots)) == len(full_slots)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["flat", "paged"])
+def test_engine_refreeze_traces_once_across_full_counts(paged):
+    """The engine's jitted fold is one program for every count of full
+    slots: folding 1, 2, 0 and all slots adds no trace after the first."""
+    from repro.serving import retrace_count
+    cfg, params = _setup()
+    eng = ContinuousEngine(params, cfg, slots=3, max_tokens=96,
+                           prefill_chunk=16, paged=paged)
+    tb = eng.pool.tail // eng.pool.bs
+    for n in (1, 2, 0, 3):
+        tail_len = np.zeros(eng.pool.slots, np.int32)
+        tail_len[:n] = eng.pool.tail
+        st = {**eng.state, "tail_len": jnp.asarray(tail_len),
+              "prefix_blocks": jnp.zeros(eng.pool.slots, jnp.int32)}
+        if paged:
+            st["refcount"] = jnp.zeros_like(st["refcount"])
+            ids = np.arange(eng.pool.slots * tb, dtype=np.int32)
+            out = eng._refreeze(st, jnp.asarray(ids.reshape(-1, tb)))
+        else:
+            out = eng._refreeze(st)
+        assert np.asarray(out["prefix_blocks"]).tolist() == \
+            [tb] * n + [0] * (eng.pool.slots - n)
+        assert retrace_count(eng._refreeze) == 1
+
+
+# ---------------------------------------------------------------------------
 # host side: allocator + prefix trie
 # ---------------------------------------------------------------------------
 
