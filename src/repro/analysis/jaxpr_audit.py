@@ -137,8 +137,9 @@ def build_audit_engine(geometry: Geometry, cfg=None):
 
     ``jax.eval_shape`` of the initializer means no parameter memory is
     ever allocated; the pool state is real but tiny (reduced config).
-    Built with ``overlap=True``: the overlapped engine's entry-point set
-    is a strict superset of the serial one (every serial transition plus
+    Built as an accelerator builds it, donating the state through every
+    program, and with ``overlap=True``: the overlapped engine's entry-point
+    set is a strict superset of the serial one (every serial transition plus
     the chained ``decode_chain`` dispatch), so every geometry cell audits
     the pipelined path too — the in-flight tick cannot smuggle a host
     transfer past the matrix."""
@@ -153,7 +154,7 @@ def build_audit_engine(geometry: Geometry, cfg=None):
         paged=geometry.paged,
         phys_blocks=AUDIT_PHYS_BLOCKS if geometry.paged else 0,
         spec=SpecConfig(k=2) if geometry.spec else None,
-        checkify=False, overlap=True)
+        checkify=False, overlap=True, donate=True)
 
 
 def collect_entries(geometry: Geometry, cfg=None

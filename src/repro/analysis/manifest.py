@@ -82,9 +82,10 @@ def _structural_hash(closed) -> str:
 
 
 def _donated(closed) -> List[int]:
-    """Donated argument indices of the entry's top-level pjit."""
+    """Donated argument indices of the entry's top-level jit (the
+    primitive is ``jit`` since JAX 0.7, ``pjit`` before)."""
     for eqn in closed.jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name in ("jit", "pjit"):
             don = eqn.params.get("donated_invars", ())
             return [i for i, d in enumerate(don) if d]
     return []

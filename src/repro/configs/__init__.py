@@ -43,6 +43,7 @@ class ArchConfig:
     head_dim: Optional[int] = None
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rms_eps: float = 1e-6        # epsilon of every RMSNorm of the model
     tie_embeddings: bool = False
     # --- MoE ---
     n_experts: int = 0

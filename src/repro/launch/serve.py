@@ -380,6 +380,8 @@ def main(argv=None):
         eng.run()
         baseline = stable_trace_counts(eng.trace_counts())
         print(f"[serve] audit: warmup traces {baseline}")
+        # what each warmed program holds on the device (to obs, if on)
+        print(f"[serve] audit: program bytes {eng.program_memory()}")
     on_token = None
     if args.log_json:
         import json as _json

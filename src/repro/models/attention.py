@@ -71,7 +71,7 @@ def _project_q(p, x, cfg):
     b = x.shape[:-1]
     q = ops.linear(x, p["wq"]).reshape(*b, cfg.padded_heads, cfg.hd)
     if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
     return q
 
 
@@ -80,7 +80,7 @@ def _project_kv(p, x, cfg):
     k = ops.linear(x, p["wk"]).reshape(*b, cfg.n_kv, cfg.hd)
     v = ops.linear(x, p["wv"]).reshape(*b, cfg.n_kv, cfg.hd)
     if cfg.qk_norm:
-        k = rms_norm(k, p["k_norm"])
+        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
     return k, v
 
 

@@ -14,7 +14,9 @@ from repro.kernels import ops
 from .module import ParamSpec
 
 
-def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm over the last axis; ``eps`` is the model's own
+    (``ArchConfig.rms_eps``)."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     return (xf * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
@@ -60,7 +62,29 @@ def unembed_apply(p, x: jax.Array, cfg) -> jax.Array:
     w = p["tok"].T.astype(cfg.cdtype) if cfg.tie_embeddings else p["lm_head"]
     # ops.linear dispatches on the leaf type (dense / sparse-bf16 / int8 /
     # packed4); never swallow kernel errors behind a silent dense fallback
-    return ops.linear(x, w, out_dtype=jnp.float32)
+    logits = ops.linear(x, w, out_dtype=jnp.float32)
+    if logits.shape[-1] != cfg.vocab:
+        # a head padded to whole lane tiles (pad_output_head): its zero
+        # columns are no tokens, so they never reach the sampler
+        logits = logits[..., :cfg.vocab]
+    return logits
+
+
+HEAD_ALIGN = 128      # lane width: the dense kernel's output tile
+
+
+def pad_output_head(params, cfg):
+    """``params`` with an untied output head whose vocabulary is not a
+    whole number of ``HEAD_ALIGN`` columns padded with zero columns to one.
+    The dense kernel pads its weight to whole tiles on every call, which on
+    a decode step copies the whole head; padded once here, it never does
+    (``unembed_apply`` slices the logits back to ``cfg.vocab``)."""
+    w = None if cfg.tie_embeddings else params["embed"]["lm_head"]
+    if not isinstance(w, jax.Array) or w.shape[-1] % HEAD_ALIGN == 0:
+        return params
+    pad = -w.shape[-1] % HEAD_ALIGN
+    head = jnp.pad(w, ((0, 0), (0, pad)))
+    return {**params, "embed": {**params["embed"], "lm_head": head}}
 
 
 # ---------------------------------------------------------------------------

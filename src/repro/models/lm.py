@@ -123,12 +123,14 @@ def _sublayer(x, p, kind, cfg, ctx, positions, memory, attn_impl,
               collect_kv: Optional[list] = None):
     mixer, ffn = kind
     if mixer == "rwkv":
-        h = rwkv_time_mix(p["tmix"], rms_norm(x, p["ln1"]), cfg, ctx)
+        h = rwkv_time_mix(p["tmix"], rms_norm(x, p["ln1"], cfg.rms_eps),
+                          cfg, ctx)
         x = ctx.constrain(x + h, ("batch", "seq", "embed"))
-        h = rwkv_channel_mix(p["tmix"], rms_norm(x, p["ln2"]), cfg)
+        h = rwkv_channel_mix(p["tmix"], rms_norm(x, p["ln2"], cfg.rms_eps),
+                             cfg)
         return ctx.constrain(x + h, ("batch", "seq", "embed"))
 
-    h = rms_norm(x, p["ln1"])
+    h = rms_norm(x, p["ln1"], cfg.rms_eps)
     if mixer == "attn":
         h = attn_apply(p["mixer"], h, cfg, ctx, positions,
                        causal=(memory is None) or None, attn_impl=attn_impl)
@@ -136,10 +138,10 @@ def _sublayer(x, p, kind, cfg, ctx, positions, memory, attn_impl,
         h = mamba_apply(p["mixer"], h, cfg, ctx)
     x = ctx.constrain(x + h, ("batch", "seq", "embed"))
     if "cross" in p and memory is not None:
-        h = attn_apply(p["cross"], rms_norm(x, p["ln_cross"]), cfg, ctx,
-                       positions, memory=memory)
+        h = attn_apply(p["cross"], rms_norm(x, p["ln_cross"], cfg.rms_eps),
+                       cfg, ctx, positions, memory=memory)
         x = ctx.constrain(x + h, ("batch", "seq", "embed"))
-    h2 = rms_norm(x, p["ln2"])
+    h2 = rms_norm(x, p["ln2"], cfg.rms_eps)
     if ffn == "moe":
         h2 = moe_apply(p["ffn"], h2, cfg, ctx)
     else:
@@ -182,7 +184,7 @@ def forward_train(params, batch: Dict[str, jax.Array], cfg, ctx,
     kinds = [layer_kind(cfg, j) for j in range(p)]
     x = _stack_forward(params["blocks"], x, cfg, ctx, positions, kinds,
                        attn_impl=attn_impl)
-    return rms_norm(x, params["final_norm"])
+    return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
 def _encdec_forward(params, batch, cfg, ctx, attn_impl):
@@ -191,14 +193,14 @@ def _encdec_forward(params, batch, cfg, ctx, attn_impl):
     positions_src = jnp.arange(src.shape[1])
     enc = _stack_forward(params["encoder"], src, cfg, ctx, positions_src,
                          [("attn", "mlp")], causal=False)
-    enc = rms_norm(enc, params["enc_norm"])
+    enc = rms_norm(enc, params["enc_norm"], cfg.rms_eps)
 
     x = embed_apply(params["embed"], batch["tokens"], cfg)
     x = ctx.constrain(x, ("batch", "seq", "embed"))
     positions = jnp.arange(x.shape[1])
     x = _stack_forward(params["blocks"], x, cfg, ctx, positions,
                        [("attn", "mlp")], memory=enc, attn_impl=attn_impl)
-    return rms_norm(x, params["final_norm"])
+    return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
 def logits_fn(params, hidden: jax.Array, cfg, ctx) -> jax.Array:
@@ -213,15 +215,15 @@ def logits_fn(params, hidden: jax.Array, cfg, ctx) -> jax.Array:
 def _sublayer_prefill(x, p, kind, cfg, ctx, positions, memory):
     mixer, ffn = kind
     if mixer == "rwkv":
-        xin1 = rms_norm(x, p["ln1"])
+        xin1 = rms_norm(x, p["ln1"], cfg.rms_eps)
         h, st = rwkv_time_mix(p["tmix"], xin1, cfg, ctx, return_state=True)
         x = x + h
-        xin2 = rms_norm(x, p["ln2"])
+        xin2 = rms_norm(x, p["ln2"], cfg.rms_eps)
         h = rwkv_channel_mix(p["tmix"], xin2, cfg)
         st = {**st, "cm_x": xin2.astype(jnp.float32)[:, -1]}
         return x + h, {"state": st}
 
-    h = rms_norm(x, p["ln1"])
+    h = rms_norm(x, p["ln1"], cfg.rms_eps)
     if mixer == "attn":
         h, (k, v) = attn_apply(p["mixer"], h, cfg, ctx, positions,
                                return_kv=True)
@@ -231,10 +233,10 @@ def _sublayer_prefill(x, p, kind, cfg, ctx, positions, memory):
         collected = {"state": st}
     x = x + h
     if "cross" in p and memory is not None:
-        h = attn_apply(p["cross"], rms_norm(x, p["ln_cross"]), cfg, ctx,
-                       positions, memory=memory)
+        h = attn_apply(p["cross"], rms_norm(x, p["ln_cross"], cfg.rms_eps),
+                       cfg, ctx, positions, memory=memory)
         x = x + h
-    h2 = rms_norm(x, p["ln2"])
+    h2 = rms_norm(x, p["ln2"], cfg.rms_eps)
     h2 = moe_apply(p["ffn"], h2, cfg, ctx) if ffn == "moe" \
         else mlp_apply(p["ffn"], h2, ctx)
     return x + h2, collected
@@ -252,7 +254,7 @@ def forward_prefill(params, batch, cfg, ctx) -> Tuple[jax.Array, Dict]:
         pos_s = jnp.arange(src.shape[1])
         enc = _stack_forward(params["encoder"], src, cfg, ctx, pos_s,
                              [("attn", "mlp")], causal=False)
-        memory = rms_norm(enc, params["enc_norm"])
+        memory = rms_norm(enc, params["enc_norm"], cfg.rms_eps)
 
     tokens = batch["tokens"]
     x = embed_apply(params["embed"], tokens, cfg)
@@ -278,7 +280,7 @@ def forward_prefill(params, batch, cfg, ctx) -> Tuple[jax.Array, Dict]:
         return xc, (out, cross_kv)
 
     x, (collected, cross) = lax.scan(body, x, params["blocks"])
-    hidden = rms_norm(x, params["final_norm"])
+    hidden = rms_norm(x, params["final_norm"], cfg.rms_eps)
     return hidden, {"layers": collected, "cross": cross,
                     "len": x.shape[1]}
 
@@ -352,15 +354,16 @@ def _sublayer_decode(x_t, p, cache_j, kind, cfg, ctx, position,
     mixer, ffn = kind
     new_cache = dict(cache_j)
     if mixer == "rwkv":
-        h, st = rwkv_time_mix_decode(p["tmix"], rms_norm(x_t, p["ln1"]),
-                                     cache_j["state"], cfg)
+        h, st = rwkv_time_mix_decode(
+            p["tmix"], rms_norm(x_t, p["ln1"], cfg.rms_eps),
+            cache_j["state"], cfg)
         x_t = x_t + h
-        h, st = rwkv_channel_mix_decode(p["tmix"], rms_norm(x_t, p["ln2"]),
-                                        st, cfg)
+        h, st = rwkv_channel_mix_decode(
+            p["tmix"], rms_norm(x_t, p["ln2"], cfg.rms_eps), st, cfg)
         new_cache["state"] = st
         return x_t + h, new_cache
 
-    h = rms_norm(x_t, p["ln1"])
+    h = rms_norm(x_t, p["ln1"], cfg.rms_eps)
     if mixer == "attn":
         h, kv = attn_decode(p["mixer"], h, cache_j["kv"], cfg, ctx, position)
         new_cache["kv"] = kv
@@ -369,10 +372,11 @@ def _sublayer_decode(x_t, p, cache_j, kind, cfg, ctx, position,
         new_cache["state"] = st
     x_t = x_t + h
     if "cross" in p and cross_kv is not None:
-        h = cross_attn_decode(p["cross"], rms_norm(x_t, p["ln_cross"]),
-                              cross_kv[0], cross_kv[1], cfg)
+        h = cross_attn_decode(
+            p["cross"], rms_norm(x_t, p["ln_cross"], cfg.rms_eps),
+            cross_kv[0], cross_kv[1], cfg)
         x_t = x_t + h
-    h2 = rms_norm(x_t, p["ln2"])
+    h2 = rms_norm(x_t, p["ln2"], cfg.rms_eps)
     if ffn == "moe":
         h2 = moe_apply(p["ffn"], h2[:, None, :], cfg, ctx)[:, 0]
     else:
@@ -468,18 +472,18 @@ def forward_panel_pooled(params, state, tokens: jax.Array,
         new_cc = {}
         for j, kind in enumerate(kinds):
             pj, cj = pp[f"l{j}"], cc[f"l{j}"]
-            h = rms_norm(xc, pj["ln1"])
+            h = rms_norm(xc, pj["ln1"], cfg.rms_eps)
             h, new_kv = pooled_attn_panel(
                 pj["mixer"], h, cj["kv"], cfg, ctx, positions,
                 prefix_blocks, tail_len, slot_mask, bs, table=table)
             xc = xc + h
-            xc = xc + _pooled_ffn(pj, kind, rms_norm(xc, pj["ln2"]),
-                                  cfg, ctx)
+            xc = xc + _pooled_ffn(
+                pj, kind, rms_norm(xc, pj["ln2"], cfg.rms_eps), cfg, ctx)
             new_cc[f"l{j}"] = {"kv": new_kv}
         return xc, new_cc
 
     x, new_layers = lax.scan(body, x, (params["blocks"], state["layers"]))
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = unembed_apply(params["embed"], x, cfg)
     logits = ctx.constrain(logits, ("batch", None, "vocab"))
     grow = qn * slot_mask.astype(jnp.int32)
@@ -550,12 +554,12 @@ def forward_prefill_chunk(params, state, tokens: jax.Array, slot: jax.Array,
         chunk_kv = {}
         for j, kind in enumerate(kinds):
             pj, cj = pp[f"l{j}"], cc[f"l{j}"]
-            h = rms_norm(xc, pj["ln1"])
+            h = rms_norm(xc, pj["ln1"], cfg.rms_eps)
             h, k_c, v_c = pooled_attn_prefill_chunk(
                 pj["mixer"], h, cj["kv"], cfg, ctx, positions, ctx_len, bs,
                 table_row=table_row)
             xc = xc + h
-            h2 = rms_norm(xc, pj["ln2"])
+            h2 = rms_norm(xc, pj["ln2"], cfg.rms_eps)
             if kind[1] == "moe":
                 h2 = moe_apply(pj["ffn"], h2, cfg, ctx)
             else:
@@ -565,7 +569,7 @@ def forward_prefill_chunk(params, state, tokens: jax.Array, slot: jax.Array,
         return xc, chunk_kv
 
     x, chunk_kv = lax.scan(body, x, (params["blocks"], slot_layers))
-    hidden = rms_norm(x, params["final_norm"])
+    hidden = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = logits_fn(params, hidden[:, -1:], cfg, ctx)[:, 0]
 
     from repro.core.sparse_kv import freeze_chunk_blocks
@@ -638,7 +642,7 @@ def forward_decode(params, cache, tokens: jax.Array, cfg, ctx
           cache["cross"] if has_cross else
           {"k": jnp.zeros((n_periods, 0)), "v": jnp.zeros((n_periods, 0))})
     x_t, new_layers = lax.scan(body, x_t, xs)
-    x_t = rms_norm(x_t, params["final_norm"])
+    x_t = rms_norm(x_t, params["final_norm"], cfg.rms_eps)
     logits = unembed_apply(params["embed"], x_t, cfg)
     logits = ctx.constrain(logits, ("batch", "vocab"))
     new_cache = dict(cache)

@@ -228,7 +228,7 @@ def rwkv_time_mix(p, x: jax.Array, cfg, ctx, return_state: bool = False):
     step = lambda c, xx: _rwkv_step(c, xx, p["bonus_u"])
     wkv_fin, ys = _chunked_scan(step, state0, xs_t, cfg.scan_chunk, cfg.remat)
     y = jnp.moveaxis(ys, 0, 1).reshape(b, s, d)              # [B,S,d]
-    y = rms_norm(y.astype(x.dtype), p["ln_x"])
+    y = rms_norm(y.astype(x.dtype), p["ln_x"], cfg.rms_eps)
     y = (y.astype(jnp.float32) * jax.nn.silu(g.astype(jnp.float32))
          ).astype(x.dtype)
     out = ops.linear(y, p["w_o"])
@@ -276,7 +276,7 @@ def rwkv_time_mix_decode(p, x_t: jax.Array, state, cfg
     hd = lambda t: t.astype(jnp.float32).reshape(b, h, dh)
     new_wkv, y = _rwkv_step(state["wkv"], (hd(r), hd(k), hd(v), hd(w)),
                             p["bonus_u"])
-    y = rms_norm(y.reshape(b, d).astype(x_t.dtype), p["ln_x"])
+    y = rms_norm(y.reshape(b, d).astype(x_t.dtype), p["ln_x"], cfg.rms_eps)
     y = (y.astype(jnp.float32) * jax.nn.silu(g.astype(jnp.float32))
          ).astype(x_t.dtype)
     out = ops.linear(y, p["w_o"])

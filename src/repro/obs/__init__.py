@@ -130,6 +130,9 @@ class Observability:
             "compressed (compressed)", tail="full")
         self._fold_compressed = r.counter(
             "repro_refreeze_slots_total", tail="compressed")
+        self._pool_bytes = r.gauge(
+            "repro_pool_bytes", "bytes of the serving pool's device state: "
+            "compressed KV blocks, dense tails, block table, lengths")
         # live tok/s: rolling median of per-tick committed/duration
         self.tok_rate = RollingWindow(64)
         # delta-sync state for external monotonic counters
@@ -260,6 +263,20 @@ class Observability:
         self._refreezes.inc()
         self._fold_full.inc(full_slots)
         self._fold_compressed.inc(compressed_slots)
+
+    def pool_built(self, nbytes: int) -> None:
+        """The engine's pool holds ``nbytes`` of device state."""
+        self._pool_bytes.set(nbytes)
+
+    def program_memory(self, program: str, stats: Dict[str, int]) -> None:
+        """One engine program's compiled memory, by kind (``argument``,
+        ``output``, ``alias``, ``temp``) in bytes, as XLA's
+        ``memory_analysis`` gives it."""
+        for kind, nbytes in stats.items():
+            self.registry.gauge(
+                "repro_program_bytes", "compiled memory of an engine "
+                "program by kind (argument, output, alias, temp)",
+                program=program, kind=kind).set(nbytes)
 
     def prefix_match(self, hit_blocks: int, lookup_blocks: int) -> None:
         self.registry.counter(

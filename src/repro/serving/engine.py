@@ -39,6 +39,7 @@ from repro.distributed import NULL_CTX, serving_sharding
 from repro.kernels import ops
 from repro.models import lm
 from repro.models.attention import DenseKVCache
+from repro.models.layers import pad_output_head
 from repro.obs import spans
 
 from . import sampling
@@ -333,6 +334,15 @@ class ContinuousEngine:
     last committed draw.  :meth:`quiesce` drains the pipeline (snapshot
     paths call it implicitly).
 
+    **Donated state** (``donate``; default: on unless the backend is the
+    CPU): every jitted program takes the pool state as a donated argument
+    and returns its successor in the same buffers, so the pool is held
+    once and a state handed to a program is deleted by it — the engine
+    only ever passes ``self.state`` and replaces it with the result.  An
+    untied output head whose width is not a whole number of 128-wide tiles
+    is padded once at build (:func:`~repro.models.layers.pad_output_head`);
+    the logits are sliced back to the vocabulary before sampling.
+
     **Program spans and names**: each tick opens the
     ``jax.profiler.TraceAnnotation`` spans of :mod:`repro.obs.spans`
     (``repro.tick`` holding ``repro.admit``, ``repro.refreeze``,
@@ -353,7 +363,8 @@ class ContinuousEngine:
                  checkify: Optional[bool] = None,
                  max_queue: int = 0, degrade_queue: int = 0,
                  faults: Optional[FaultPlan] = None, clock=None,
-                 obs=None, overlap: bool = False):
+                 obs=None, overlap: bool = False,
+                 donate: Optional[bool] = None):
         if mesh is not None:
             # mesh-sharded serving: slots over the data axes, KV heads over
             # the model axis.  The ctx also constrains activations inside
@@ -367,6 +378,16 @@ class ContinuousEngine:
         self.cfg = cfg
         self.ctx = ctx
         self.mesh = mesh
+        # an untied head of unaligned width is padded once, here, instead
+        # of by the dense kernel in every step
+        params = pad_output_head(params, cfg)
+        # every program takes the pool state and returns its successor:
+        # donated, the state is updated in place and the pool is held once.
+        # The CPU backend keeps its inputs unless asked: its memory is the
+        # host's, and CPU tests and planted faults read a state after the
+        # step it fed
+        self.donate = (jax.default_backend() != "cpu" if donate is None
+                       else bool(donate))
         max_tokens = max_tokens or 4 * cfg.kv_tail
         if not bs:
             # largest tail divisor <= min(128, prefill_chunk): chunks stay
@@ -412,7 +433,7 @@ class ContinuousEngine:
             params = jax.device_put(params, par_sh)
             self.state = jax.device_put(self.state, st_sh)
 
-            def _jit(fn, in_s, out_s):
+            def _jit(fn, in_s, out_s, donate):
                 # the Pallas kernels cannot be partitioned by XLA: they
                 # trace as shard_maps that keep the serving placement
                 # (kernels/ops.py).  wraps keeps fn's name, so the
@@ -422,7 +443,7 @@ class ContinuousEngine:
                     with ops.kernel_mesh(ctx):
                         return fn(*args)
                 return jax.jit(on_mesh, in_shardings=in_s,
-                               out_shardings=out_s)
+                               out_shardings=out_s, donate_argnums=donate)
         elif self.pool.checkify:
             # sanitized mode: the pool transitions plant checkify.check
             # invariants, which a plain jit cannot trace — functionalize
@@ -431,9 +452,10 @@ class ContinuousEngine:
             # forwarded _cache_size.
             st_sh = tok_sh = vec_sh = rep = par_sh = None
 
-            def _jit(fn, in_s, out_s):
+            def _jit(fn, in_s, out_s, donate):
                 checked = jax.jit(
-                    functools.wraps(fn)(cache_pool_checkified_raw(fn)))
+                    functools.wraps(fn)(cache_pool_checkified_raw(fn)),
+                    donate_argnums=donate)
 
                 @functools.wraps(fn)
                 def run(*args):
@@ -441,13 +463,16 @@ class ContinuousEngine:
                     err.throw()
                     return out
                 run._cache_size = checked._cache_size
+                run.lower = checked.lower
                 return run
         else:
             st_sh = tok_sh = vec_sh = rep = par_sh = None
 
-            def _jit(fn, in_s, out_s):
-                return jax.jit(fn)
+            def _jit(fn, in_s, out_s, donate):
+                return jax.jit(fn, donate_argnums=donate)
         self.params = params
+        # the position of the state among each program's arguments
+        st_at = lambda i: (i,) if self.donate else ()  # noqa: E731
 
         # sampling stays on device: only [slots]-sized token + logprob
         # vectors cross the host boundary each tick, never [slots, vocab]
@@ -494,21 +519,24 @@ class ContinuousEngine:
             return sampling.set_lane(st, slot, t, k, p, key)
 
         self._decode = _jit(_decode, (par_sh, st_sh, tok_sh, vec_sh),
-                            (vec_sh, vec_sh, st_sh))
+                            (vec_sh, vec_sh, st_sh), st_at(1))
         if paged:
             self._prefill_chunk = _jit(
                 _prefill, (par_sh, st_sh, rep, rep, rep, rep),
-                (rep, rep, st_sh))
-            self._refreeze = _jit(_refreeze, (st_sh, rep), st_sh)
-            self._assign = _jit(_assign, (st_sh, rep, rep, rep), st_sh)
+                (rep, rep, st_sh), st_at(1))
+            self._refreeze = _jit(_refreeze, (st_sh, rep), st_sh, st_at(0))
+            self._assign = _jit(_assign, (st_sh, rep, rep, rep), st_sh,
+                                st_at(0))
         else:
             self._prefill_chunk = _jit(
-                _prefill, (par_sh, st_sh, rep, rep, rep), (rep, rep, st_sh))
-            self._refreeze = _jit(_refreeze, (st_sh,), st_sh)
+                _prefill, (par_sh, st_sh, rep, rep, rep), (rep, rep, st_sh),
+                st_at(1))
+            self._refreeze = _jit(_refreeze, (st_sh,), st_sh, st_at(0))
             self._assign = None
-        self._release = _jit(self.pool.release, (st_sh, rep), st_sh)
+        self._release = _jit(self.pool.release, (st_sh, rep), st_sh,
+                             st_at(0))
         self._set_lane = _jit(_set_lane, (st_sh, rep, rep, rep, rep, rep),
-                              st_sh)
+                              st_sh, st_at(0))
 
         # speculative decoding: one jitted draft–verify step scores all
         # K+1 panel positions in a single forward over the pooled cache,
@@ -541,7 +569,7 @@ class ContinuousEngine:
 
             self._verify = _jit(_verify,
                                 (par_sh, st_sh, tok_sh, vec_sh, vec_sh),
-                                (tok_sh, tok_sh, vec_sh, st_sh))
+                                (tok_sh, tok_sh, vec_sh, st_sh), st_at(1))
 
         # overlapped pipeline: the chained decode entry consumes the
         # PREVIOUS tick's un-synced token vector as a device operand and
@@ -564,7 +592,7 @@ class ContinuousEngine:
             self._decode_chain = _jit(
                 _decode_chain,
                 (par_sh, st_sh, vec_sh, vec_sh, vec_sh, vec_sh),
-                (vec_sh, vec_sh, st_sh))
+                (vec_sh, vec_sh, st_sh), st_at(1))
             # steady-state device-operand caches: on an uninterrupted
             # chain the override vectors are all-zero and the decode mask
             # repeats, so reuse ONE transferred array per shape instead of
@@ -610,6 +638,8 @@ class ContinuousEngine:
         # (tests/test_obs.py pins all three properties).
         self._obs = obs
         self._tick_committed = 0          # tokens committed this tick
+        if obs is not None:
+            obs.pool_built(self.pool.nbytes())
         if obs is not None and faults is not None:
             faults.on_fire = (
                 lambda site, tick: obs.fault(site, tick,
@@ -762,6 +792,33 @@ class ContinuousEngine:
         self._sync_inflight(events)
         self._flush_releases()
         return events
+
+    def program_memory(self) -> Dict[str, Dict[str, int]]:
+        """``{program: {kind: bytes}}`` for every program that has run:
+        XLA's ``memory_analysis`` of its compiled executable (``argument``,
+        ``output``, ``alias`` -- the outputs that reuse a donated argument
+        -- and ``temp``).  Meant for the end of a warm-up: each program is
+        then a compile-cache hit at its abstract arguments, so nothing
+        compiles (the prefill program is read at the scheduler's chunk
+        width, and skipped for unchunked prefill, which compiles per
+        prompt length).  With telemetry attached the numbers also go to
+        ``repro.obs`` (``repro_program_bytes``)."""
+        ran = self.trace_counts()
+        if self.scheduler.chunk is None:
+            ran.pop("prefill_chunk")
+        out: Dict[str, Dict[str, int]] = {}
+        for name, (fn, args) in self.entry_points(
+                self.scheduler.chunk or 0).items():
+            if not ran.get(name):
+                continue
+            m = fn.lower(*args).compile().memory_analysis()
+            out[name] = {"argument": m.argument_size_in_bytes,
+                         "output": m.output_size_in_bytes,
+                         "alias": m.alias_size_in_bytes,
+                         "temp": m.temp_size_in_bytes}
+            if self._obs is not None:
+                self._obs.program_memory(name, out[name])
+        return out
 
     def generate_batch(self, prompts: jax.Array,
                        params: Optional[SamplingParams] = None) -> jax.Array:

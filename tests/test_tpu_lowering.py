@@ -175,3 +175,43 @@ def test_prefix_partial_attention_compiles(one_chip):
             interpret=False),
         q, *_kv_operands(one_chip, False), nb)
     assert "tpu_custom_call" in txt
+
+
+# phi3-mini-3.8b: d_model 3072, d_ff 8192, 32 heads = 32 KV heads (group
+# 1), head_dim 96; decode at 8 slots goes through sparse_gemv
+PHI3_LINEAR_SHAPES = [(3072, 3072), (3072, 8192), (8192, 3072)]
+PHI3_HKV, PHI3_D = 32, 96
+
+
+@pytest.mark.parametrize("k,n", PHI3_LINEAR_SHAPES)
+def test_sparse_gemv_compiles_at_phi3_widths(one_chip, k, n):
+    x = jax.ShapeDtypeStruct((8, k), jnp.bfloat16, sharding=one_chip)
+    txt = _compile_text(
+        lambda x, w: sparse_gemv_pallas(x, w, interpret=False),
+        x, _weight(one_chip, k, n, "bf16"))
+    assert "tpu_custom_call" in txt
+
+
+def test_fused_paged_decode_attention_compiles_mha_head_dim_96(one_chip):
+    """The paged fused decode kernel at Phi-3's geometry: query-per-KV
+    group 1 (MHA) and head_dim 96, whose bitmap rows straddle rows of the
+    word view, with the block table as a scalar-prefetch operand."""
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,  # noqa: E731
+                                                    sharding=one_chip)
+    ck = balanced_capacity(0.7, (BS, PHI3_D))
+    cv = balanced_capacity(0.5, (BS, PHI3_D))
+    words = BS * PHI3_D // 32
+    lead = (N_PHYS, PHI3_HKV)
+    kv = (sds(lead + (words,), jnp.uint32), sds(lead + (ck,), jnp.bfloat16),
+          sds(lead + (words,), jnp.uint32), sds(lead + (cv,), jnp.bfloat16))
+    q = sds((SLOTS, PHI3_HKV, 1, PHI3_D), jnp.bfloat16)
+    tail = sds((SLOTS, PHI3_HKV, TAIL, PHI3_D), jnp.bfloat16)
+    lens = sds((SLOTS,), jnp.int32)
+    table = sds((SLOTS, PREFIX_BLOCKS), jnp.int32)
+
+    def fn(q, kb, kv, vb, vv, kt, vt, nb, tl, tbl):
+        return sparse_decode_attention_fused_pallas(
+            q, kb, kv, vb, vv, kt, vt, nb, tl, tbl, bs=BS,
+            sm_scale=PHI3_D ** -0.5, interpret=False, group=1)
+    txt = _compile_text(fn, q, *kv, tail, tail, lens, lens, table)
+    assert "tpu_custom_call" in txt
