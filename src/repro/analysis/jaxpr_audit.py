@@ -31,7 +31,8 @@ registry :meth:`trace_counts` reports on), traces each under its
 ``table-gather-bounds``
     Any gather/scatter whose operand leads with the paged arena axis
     (``n_phys`` rows — the audit geometry picks a prime arena size so the
-    dimension is unambiguous) must stay in ``CLIP`` or ``FILL_OR_DROP``
+    dimension is unambiguous), or carries it second under the stacked
+    arena's period axis, must stay in ``CLIP`` or ``FILL_OR_DROP``
     mode.  ``PROMISE_IN_BOUNDS`` on a block-table access would turn a
     corrupt table entry into out-of-bounds memory traffic instead of the
     pool's documented clip/sentinel-drop discipline.
@@ -101,7 +102,8 @@ DEFAULT_GEOMETRIES: Tuple[Geometry, ...] = (
 )
 
 # distinctive prime arena size: no other dimension in the reduced config
-# collides with it, so "operand leads with n_phys" identifies arena ops
+# collides with it, so "operand leads with n_phys" (or, for the stacked
+# [P, n_phys, ...] arena, carries it second) identifies arena ops
 AUDIT_PHYS_BLOCKS = 29
 
 
@@ -296,7 +298,7 @@ def audit_jaxpr(closed, entry: str, geometry: Geometry,
             operand = eqn.invars[0].aval
             shape = getattr(operand, "shape", ())
             mode = eqn.params.get("mode")
-            if (shape and shape[0] == n_phys and mode is not None
+            if (n_phys in tuple(shape)[:2] and mode is not None
                     and mode == promise):
                 rel, line = _frame(eqn)
                 findings.append(AuditFinding(

@@ -236,6 +236,41 @@ def append_tail_panel(tail: jax.Array, new: jax.Array, tail_len: jax.Array,
         nw.astype(tl.dtype), mode="drop"))(tail, new, idx)
 
 
+# the compressed-block storage leaves of one layer's kv tree: the paged
+# arena's persistent content (tails are private in-flight state)
+ARENA_KEYS = ("k_bitmap", "k_values", "v_bitmap", "v_values")
+ARENA_LANES = 128
+# a tile's rows are stored as a multiple of this: the chip lays a
+# [..., Hkv, rows, 128] arena out in (8, 128) tiles only when the rows fill
+# them, and otherwise puts Hkv minor to the rows, which the fused kernel's
+# block fetch would have relaid out (a copy of the whole arena) every step
+ARENA_SUBLANES = 8
+
+
+def arena_rows(x: int) -> int:
+    """Stored rows of one paged-arena block whose packed leaf holds ``x``
+    words or values: ``ceil(x / 128)`` rounded up to a multiple of 8."""
+    rows = -(-x // ARENA_LANES)
+    return -(-rows // ARENA_SUBLANES) * ARENA_SUBLANES
+
+
+def to_arena_tiles(a: jax.Array, rows: int) -> jax.Array:
+    """Packed blocks ``[..., X]`` -> arena tiles ``[..., rows, 128]``, the
+    padding zero (never selected: a block's bitmap words and values occupy
+    its first ``X`` entries)."""
+    pad = rows * ARENA_LANES - a.shape[-1]
+    a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, pad)])
+    return a.reshape(*a.shape[:-1], rows, ARENA_LANES)
+
+
+def from_arena_tiles(a: jax.Array, x: Optional[int] = None) -> jax.Array:
+    """Arena tiles ``[..., rows, 128]`` -> packed blocks ``[..., X]`` (the
+    stored width when ``x`` is None: the padding values are zeros that no
+    bitmap bit selects)."""
+    flat = a.reshape(*a.shape[:-2], a.shape[-2] * a.shape[-1])
+    return flat if x is None else flat[..., :x]
+
+
 def pooled_view(bitmap: jax.Array, values: jax.Array, bs: int, d: int
                 ) -> BlockSparseWeight:
     """Pooled block arrays ``[B, Hkv, Sb, X]`` -> the structured

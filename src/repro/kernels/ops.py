@@ -319,6 +319,7 @@ def sparse_decode_attention_paged(q: jax.Array,
                                   k_bitmap: jax.Array, k_values: jax.Array,
                                   v_bitmap: jax.Array, v_values: jax.Array,
                                   table: jax.Array,
+                                  layer: jax.Array,
                                   hkv: int,
                                   sm_scale: float,
                                   bs: int,
@@ -332,32 +333,35 @@ def sparse_decode_attention_paged(q: jax.Array,
     block-table row.
 
     q as in :func:`sparse_decode_attention` (``[B, Hq, D]`` tick or
-    ``[B, Q, Hq, D]`` panel); ``k_bitmap`` uint32 ``[n_phys, Hkv, w]`` /
-    ``k_values [n_phys, Hkv, Ck]`` (same for v) the shared arena; ``table``
-    int32 ``[B, Sb]`` physical block ids (entries past
-    ``prefix_len // bs`` are dead but must stay in range).  Tail ring and
-    length semantics are identical to the flat entry — paging touches only
-    where prefix blocks are FETCHED from, never what they mean.
+    ``[B, Q, Hq, D]`` panel); ``k_bitmap`` uint32 / ``k_values`` (same for
+    v) the shared arena of EVERY layer as the pool stores it,
+    ``[P, n_phys, Hkv, rows, 128]`` tiles; ``layer`` the int32 period
+    index to attend in; ``table`` int32 ``[B, Sb]`` physical block ids
+    (entries past ``prefix_len // bs`` are dead but must stay in range).
+    Tail ring and length semantics are identical to the flat entry —
+    paging touches only where prefix blocks are FETCHED from, never what
+    they mean.
 
-    XLA backend: gather each slot's logical prefix out of the arena and
-    reuse the flat reference semantics verbatim (the defining oracle).
-    Pallas backend: the fused kernel takes the table as a scalar-prefetch
-    operand and its prefix phase loads block ``table[slot, i]`` — the
-    shared blocks are streamed per slot but STORED once, which is where
-    the memory-bound decode wins.
+    XLA backend: gather each slot's logical prefix out of the layer's
+    arena and reuse the flat reference semantics verbatim (the defining
+    oracle).  Pallas backend: the fused kernel takes the layer and the
+    table as scalar-prefetch operands and its prefix phase loads the tile
+    ``(layer, table[slot, i], head)`` where it lies — no per-layer slice
+    or relayout of the arena, and the shared blocks are streamed per slot
+    but STORED once, which is where the memory-bound decode wins.
     """
     interp = _pallas()
     d = q.shape[-1]
     if interp is None:
-        k_sp = ref.gather_paged_prefix(table, k_bitmap, k_values, bs, d)
-        v_sp = ref.gather_paged_prefix(table, v_bitmap, v_values, bs, d)
+        k_sp = ref.gather_paged_prefix(table, layer, k_bitmap, k_values, bs, d)
+        v_sp = ref.gather_paged_prefix(table, layer, v_bitmap, v_values, bs, d)
         return sparse_decode_attention(q, k_sp, v_sp, hkv, sm_scale,
                                        k_tail, v_tail, tail_len, prefix_len)
     if q.ndim == 4 and q.shape[1] == 1:
         # Q == 1 panel IS a decode tick (see sparse_decode_attention)
         o = sparse_decode_attention_paged(
-            q[:, 0], k_bitmap, k_values, v_bitmap, v_values, table, hkv,
-            sm_scale, bs, k_tail, v_tail, tail_len, prefix_len)
+            q[:, 0], k_bitmap, k_values, v_bitmap, v_values, table, layer,
+            hkv, sm_scale, bs, k_tail, v_tail, tail_len, prefix_len)
         return o[:, None]
     panel = q.ndim == 4
     if panel:
@@ -380,12 +384,13 @@ def sparse_decode_attention_paged(q: jax.Array,
     if pad:
         k_tail = jnp.pad(k_tail, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v_tail = jnp.pad(v_tail, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    arena = (None, "kv_heads", None)    # physical blocks reach every slot
+    # physical blocks reach every slot: only the KV heads split the arena
+    arena = (None, None, "kv_heads", None, None)
     o = _run(sparse_decode_attention_fused_pallas,
              (qg, k_bitmap, k_values, v_bitmap, v_values, k_tail, v_tail,
-              n_blocks, tl, table),
+              n_blocks, tl, table, jnp.asarray(layer, jnp.int32)),
              [_SLOT_HEAD] + [arena] * 4 + [_SLOT_HEAD] * 2
-             + [("slots",)] * 2 + [("slots", None)], _SLOT_HEAD,
+             + [("slots",)] * 2 + [("slots", None), ()], _SLOT_HEAD,
              {"slots": b, "kv_heads": hkv},
              bs=bs, sm_scale=sm_scale, interpret=interp, group=g)
     if panel:

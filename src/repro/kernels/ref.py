@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sparse_format import BlockSparseWeight, unpack
+from repro.core.sparse_kv import from_arena_tiles
 from repro.core.quant import quantize_act_int8
 
 
@@ -64,15 +65,18 @@ def sparse_matmul_int8_ref(x: jax.Array, sw: BlockSparseWeight,
     return out[:, :n].astype(out_dtype)
 
 
-def gather_paged_prefix(table: jax.Array, bitmap: jax.Array,
-                        values: jax.Array, bs: int, d: int
+def gather_paged_prefix(table: jax.Array, layer: jax.Array,
+                        bitmap: jax.Array, values: jax.Array, bs: int, d: int
                         ) -> BlockSparseWeight:
     """Paged arena + per-slot block table -> the flat pooled-prefix view.
 
-    ``bitmap [n_phys, Hkv, w]`` / ``values [n_phys, Hkv, C]`` hold every
-    compressed block ONCE; ``table [B, Sb]`` int32 maps each slot's
-    logical block ``i`` to its physical id.  The gather materializes each
-    slot's logical prefix (``[B, Hkv, Sb, X]``) and wraps it in the
+    ``bitmap`` / ``values`` ``[P, n_phys, Hkv, rows, 128]`` hold every
+    compressed block of every layer ONCE, as the pool's tiles; ``layer``
+    int32 picks the period and ``table [B, Sb]`` int32 maps each slot's
+    logical block ``i`` to its physical id.  One gather of both indices
+    (clipped in range, like every arena access) materializes each slot's
+    logical prefix (``[B, Hkv, Sb, X]``; the values keep the tiles'
+    zero padding, which no bitmap bit selects) and wraps it in the
     structured :class:`BlockSparseWeight` view the flat reference
     semantics consume — this IS the oracle for the paged kernel's index
     indirection: paged attention == gather-then-flat-attention.  Table
@@ -80,8 +84,10 @@ def gather_paged_prefix(table: jax.Array, bitmap: jax.Array,
     blocks; callers mask them with ``prefix_len`` exactly as on the flat
     path.
     """
-    bm = jnp.take(bitmap, table, axis=0).transpose(0, 2, 1, 3)
-    vl = jnp.take(values, table, axis=0).transpose(0, 2, 1, 3)
+    take = lambda a: a.at[layer, table].get(  # noqa: E731
+        mode="clip").transpose(0, 2, 1, 3, 4)          # [B, Hkv, Sb, r, 128]
+    bm = from_arena_tiles(take(bitmap), bs * d // 32)
+    vl = from_arena_tiles(take(values))
     sb = table.shape[1]
     return BlockSparseWeight(
         bitmap=bm[:, :, :, None, :], values=vl[:, :, :, None, :],

@@ -232,12 +232,12 @@ def _fused_kernel(nb_ref, tl_ref, q_ref, kbm_ref, kval_ref, vbm_ref,
         o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
-def _fused_kernel_paged(tbl_ref, *refs, **kw):
-    """Paged wrapper: the block table rides in as a scalar-prefetch operand
-    consumed ONLY by the index maps (one level of indirection on the
-    prefix phase's block fetch); the kernel body is byte-for-byte the flat
-    ``_fused_kernel``."""
-    del tbl_ref
+def _fused_kernel_paged(layer_ref, tbl_ref, *refs, **kw):
+    """Paged wrapper: the layer index and the block table ride in as
+    scalar-prefetch operands consumed ONLY by the index maps (which tile of
+    the stacked arena the prefix phase fetches); the kernel body is
+    byte-for-byte the flat ``_fused_kernel``."""
+    del layer_ref, tbl_ref
     _fused_kernel(*refs, **kw)
 
 
@@ -249,7 +249,8 @@ def sparse_decode_attention_fused_pallas(
         k_tail: jax.Array, v_tail: jax.Array,
         n_blocks: jax.Array | None = None,
         tail_len: jax.Array | None = None,
-        block_table: jax.Array | None = None, *,
+        block_table: jax.Array | None = None,
+        layer: jax.Array | None = None, *,
         bs: int, sm_scale: float, interpret: bool,
         group: int | None = None) -> jax.Array:
     """Fused prefix+tail flash-decode: final attention in ONE pallas_call.
@@ -271,19 +272,24 @@ def sparse_decode_attention_fused_pallas(
                    causal — the verify step appends one K/V per panel
                    query).  None means the whole ring is valid to query 0.
     block_table:   optional int32 [B, Sb] — PAGED prefix: the bitmap/values
-                   operands are then a pool-global arena
-                   ``[n_phys, Hkv, X]`` and the grid's prefix phase loads
-                   physical block ``block_table[slot, i]`` instead of slot
-                   block ``(slot, i)``.  The table rides in as a
+                   operands are then the pool-global arena of every layer,
+                   ``[P, n_phys, Hkv, rows, 128]`` tiles as the pool
+                   stores them, and the grid's prefix phase loads the tile
+                   ``(layer, block_table[slot, i], head)`` in place instead
+                   of slot block ``(slot, i)``.  The table rides in as a
                    scalar-prefetch operand so the index maps (which run
                    ahead of the kernel body to schedule the block DMAs)
                    can read it; every entry must address real storage
                    (``< n_phys``) even past ``n_blocks`` — dead fetches
                    are gated off the softmax by the same ``n_blocks``
                    check as the flat path, so they are never *read*.
+    layer:         int32 scalar, paged only — the arena's period index,
+                   likewise a scalar-prefetch operand.
     Per-slot ``n_blocks``/``tail_len`` (and the table) are scalar-prefetch
-    operands; every block is fetched as a tile whose last two dims are the
-    array's own (:func:`repro.kernels.common.tile_view`).
+    operands; a flat block is fetched as a tile whose last two dims are the
+    array's own (:func:`repro.kernels.common.tile_view`).  The paged arena
+    is already stored as tiles, so no view of it is taken: the kernel
+    reads the pool's own buffers.
     Returns out [B, Hkv, Q*G, D] f32 — softmax-normalized over the union
     of valid prefix and tail positions (all-empty slots return zeros).
     """
@@ -294,14 +300,15 @@ def sparse_decode_attention_fused_pallas(
     paged = block_table is not None
     leaves = (k_bitmap, k_values, v_bitmap, v_values)
     if paged:
-        if k_bitmap.ndim != 3:   # [n_phys, Hkv, X] arena
-            raise ValueError(f"paged arena must be rank-3, got {k_bitmap.shape}")
+        if k_bitmap.ndim != 5 or layer is None:
+            raise ValueError("the paged arena is [P, n_phys, Hkv, rows, 128] "
+                             f"tiles with a layer index, got {k_bitmap.shape}")
         sb = block_table.shape[1]
-        views = [tile_view(a) for a in leaves]
-        # THE paged change: the prefix phase's block index goes through the
-        # table (physical block major, KV head minor in the arena view)
-        pre = lambda bb, h, s, tbl, nb, tl: (
-            tbl[bb, jnp.minimum(s, sb - 1)] * hkv + h, 0, 0)
+        views = list(leaves)
+        # THE paged change: the prefix phase's tile index goes through the
+        # table, in the layer's slice of the stacked arena
+        pre = lambda bb, h, s, lyr, tbl, nb, tl: (
+            lyr[0], tbl[bb, jnp.minimum(s, sb - 1)], h, 0, 0)
     else:
         sb = k_bitmap.shape[2]
         views, pre = _flat_views(b, hkv, sb, *leaves)
@@ -315,20 +322,24 @@ def sparse_decode_attention_fused_pallas(
         tail_len = jnp.full((b,), tp, jnp.int32)
     scalars = [n_blocks.astype(jnp.int32), tail_len.astype(jnp.int32)]
     if paged:
-        scalars.insert(0, block_table.astype(jnp.int32))
+        scalars[:0] = [jnp.reshape(layer, (1,)).astype(jnp.int32),
+                       block_table.astype(jnp.int32)]
 
     # index maps clamp into range on the other phase's steps (the fetched
     # block is ignored there — the pl.when gates never fire)
     tail = lambda bb, h, s, *_: (bb, h, jnp.maximum(s - sb, 0), 0)
     bcast = lambda bb, h, s, *_: (bb, h, 0, 0)
     kernel = _fused_kernel_paged if paged else _fused_kernel
+    # a prefix block is one (rows, 128) tile, under a unit block axis
+    block = ((lambda a: (None, None, 1) + a.shape[3:]) if paged
+             else (lambda a: (1,) + a.shape[1:]))
     return pl.pallas_call(
         partial(kernel, bs=bs, d=d, sm_scale=sm_scale, sb=sb, g=g),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(b, hkv, sb + tb),
             in_specs=[pl.BlockSpec((1, 1, qg, d), bcast)]
-            + [pl.BlockSpec((1,) + a.shape[1:], pre) for a in views]
+            + [pl.BlockSpec(block(a), pre) for a in views]
             + [pl.BlockSpec((1, 1, bs, d), tail)] * 2,
             out_specs=pl.BlockSpec((1, 1, qg, d), bcast),
             scratch_shapes=[

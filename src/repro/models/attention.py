@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from repro.kernels import ops
 from repro.core.sparse_format import unpack
 from repro.core.sparse_kv import (SparseKVCache, append_tail_panel,
-                                  append_token, pooled_view)
+                                  append_token, from_arena_tiles,
+                                  pooled_view)
 from .module import ParamSpec
 from .layers import rms_norm, rope_angles, apply_rope
 from .flash import blocked_attention, full_attention
@@ -186,7 +187,8 @@ def attn_decode(p, x_t: jax.Array, cache, cfg, ctx,
 def pooled_attn_panel(p, x: jax.Array, kv: Dict[str, jax.Array], cfg,
                       ctx, positions: jax.Array, prefix_blocks: jax.Array,
                       tail_len: jax.Array, slot_mask: jax.Array, bs: int,
-                      table: Optional[jax.Array] = None
+                      table: Optional[jax.Array] = None,
+                      layer: Optional[jax.Array] = None
                       ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """THE pooled serving attention: one ``[B, Qn]`` query panel per layer.
 
@@ -213,9 +215,11 @@ def pooled_attn_panel(p, x: jax.Array, kv: Dict[str, jax.Array], cfg,
 
     ``table`` (int32 ``[B, Sb]``, paged pool only) switches the frozen
     prefix to the pool-global arena layout: ``kv``'s compressed leaves are
-    then ``[n_phys, Hkv, X]`` shared storage and each slot's blocks are
-    reached through its table row — same math, one indirection on the
-    fetch.  The dense tail stays per-slot either way.
+    then the shared storage of EVERY layer, ``[P, n_phys, Hkv, rows,
+    128]``, ``layer`` (int32 scalar) is this layer's period, and each
+    slot's blocks are reached through its table row — same math, one
+    indirection on the fetch, and the arena is read where it lies.  The
+    dense tail stays per-slot either way.
     """
     b, qn, _ = x.shape
     hq, hkv, hd = cfg.padded_heads, cfg.n_kv, cfg.hd
@@ -236,8 +240,8 @@ def pooled_attn_panel(p, x: jax.Array, kv: Dict[str, jax.Array], cfg,
     if table is not None:
         o = ops.sparse_decode_attention_paged(
             q, kv["k_bitmap"], kv["k_values"], kv["v_bitmap"],
-            kv["v_values"], table, hkv, sm, bs, k_tail, v_tail, t_att,
-            prefix_len=prefix_blocks * bs)
+            kv["v_values"], table, layer, hkv, sm, bs, k_tail, v_tail,
+            t_att, prefix_len=prefix_blocks * bs)
     else:
         k_sp = pooled_view(kv["k_bitmap"], kv["k_values"], bs, hd)
         v_sp = pooled_view(kv["v_bitmap"], kv["v_values"], bs, hd)
@@ -251,7 +255,8 @@ def pooled_attn_panel(p, x: jax.Array, kv: Dict[str, jax.Array], cfg,
 def pooled_attn_prefill_chunk(p, x: jax.Array, kv: Dict[str, jax.Array],
                               cfg, ctx, positions: jax.Array,
                               ctx_len: jax.Array, bs: int,
-                              table_row: Optional[jax.Array] = None
+                              table_row: Optional[jax.Array] = None,
+                              layer: Optional[jax.Array] = None
                               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Chunked-prefill attention for ONE slot of the pooled cache.
 
@@ -264,9 +269,11 @@ def pooled_attn_prefill_chunk(p, x: jax.Array, kv: Dict[str, jax.Array],
     caller can freeze the chunk into the pool.
 
     ``table_row`` (int32 ``[Sb]``, paged pool only): ``kv``'s compressed
-    leaves are the shared ``[n_phys, Hkv, X]`` arena and the slot's frozen
+    leaves are the shared arena of every layer (``[P, n_phys, Hkv, rows,
+    128]`` tiles), ``layer`` this layer's period, and the slot's frozen
     prefix is gathered through its block-table row before decompression —
-    a prefix-cache hit means these are blocks some OTHER request froze.
+    only the slot's pages move, never the arena; a prefix-cache hit means
+    these are blocks some OTHER request froze.
     """
     b, c, _ = x.shape
     hq, hkv, hd = cfg.padded_heads, cfg.n_kv, cfg.hd
@@ -281,14 +288,15 @@ def pooled_attn_prefill_chunk(p, x: jax.Array, kv: Dict[str, jax.Array],
     v = v.transpose(0, 2, 1, 3)
 
     if table_row is not None:
-        # gather the slot's logical blocks out of the shared arena, then
+        # gather the slot's logical blocks out of the layer's arena, then
         # decompress exactly as the flat path would.  Explicit clip mode:
         # table entries are clipped in range at write time, and the jaxpr
         # audit (repro.analysis) rejects PROMISE_IN_BOUNDS arena access
-        arena = lambda a: jnp.take(a, table_row, axis=0, mode="clip")
-        view = lambda bm, vl: pooled_view(
-            arena(bm).transpose(1, 0, 2)[None],
-            arena(vl).transpose(1, 0, 2)[None], bs, hd)
+        arena = lambda a, x=None: from_arena_tiles(  # noqa: E731
+            a.at[layer, table_row].get(mode="clip").transpose(1, 0, 2, 3),
+            x)[None]                                 # [1, Hkv, Sb, X]
+        view = lambda bm, vl: pooled_view(  # noqa: E731
+            arena(bm, bs * hd // 32), arena(vl), bs, hd)
         k_ctx = unpack(view(kv["k_bitmap"], kv["k_values"]))
         v_ctx = unpack(view(kv["v_bitmap"], kv["v_values"]))
     else:

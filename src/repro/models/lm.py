@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.sparse_kv import SparseKVCache, abstract_cache, freeze_prefix
+from repro.core.sparse_kv import (ARENA_KEYS, SparseKVCache, abstract_cache,
+                                  freeze_prefix, to_arena_tiles)
 from repro.kernels import ops
 from . import module as mod
 from .module import ParamSpec
@@ -453,6 +454,12 @@ def forward_panel_pooled(params, state, tokens: jax.Array,
     passthrough, and every shape is static: one trace per
     (pool geometry, Qn), whatever the accept lengths turn out to be.
 
+    Paged pool: the arena leaves stay out of the layer scan.  The body
+    closes over the stacked ``[P, n_phys, Hkv, rows, 128]`` arena and takes
+    its period index from the scan, and the kernel fetches each block
+    where it lies; only the per-slot tails ride the scan.  The arena comes
+    back as it went in (donated, its buffers are aliased, never rewritten).
+
     Returns (logits [B, Qn, V] f32, new state); unknown ``state`` keys
     (e.g. the sampler lanes) pass through untouched.
     """
@@ -463,26 +470,31 @@ def forward_panel_pooled(params, state, tokens: jax.Array,
     positions = state["pos"][:, None] + jnp.arange(qn)[None, :]
     prefix_blocks = state["prefix_blocks"]
     tail_len = state["tail_len"]
-    # paged pool: the block table is pool-level state threaded to every
-    # layer's attention (the arena leaves themselves ride the scan)
+    # paged pool: the block table and the stacked arena are pool-level
+    # state threaded to every layer's attention, the layer's period index
+    # rides the scan
     table = state.get("table")
+    arena, scanned, layer_ids = _split_arena(state)
 
     def body(xc, xs):
-        pp, cc = xs
+        pp, cc, layer = xs
         new_cc = {}
         for j, kind in enumerate(kinds):
-            pj, cj = pp[f"l{j}"], cc[f"l{j}"]
+            pj, name = pp[f"l{j}"], f"l{j}"
             h = rms_norm(xc, pj["ln1"], cfg.rms_eps)
             h, new_kv = pooled_attn_panel(
-                pj["mixer"], h, cj["kv"], cfg, ctx, positions,
-                prefix_blocks, tail_len, slot_mask, bs, table=table)
+                pj["mixer"], h, {**cc[name]["kv"], **arena.get(name, {})},
+                cfg, ctx, positions, prefix_blocks, tail_len, slot_mask, bs,
+                table=table, layer=layer)
             xc = xc + h
             xc = xc + _pooled_ffn(
                 pj, kind, rms_norm(xc, pj["ln2"], cfg.rms_eps), cfg, ctx)
-            new_cc[f"l{j}"] = {"kv": new_kv}
+            new_cc[name] = {"kv": {k: new_kv[k] for k in cc[name]["kv"]}}
         return xc, new_cc
 
-    x, new_layers = lax.scan(body, x, (params["blocks"], state["layers"]))
+    x, new_layers = lax.scan(body, x,
+                             (params["blocks"], scanned, layer_ids))
+    new_layers = _join_arena(arena, new_layers)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = unembed_apply(params["embed"], x, cfg)
     logits = ctx.constrain(logits, ("batch", None, "vocab"))
@@ -493,12 +505,38 @@ def forward_panel_pooled(params, state, tokens: jax.Array,
     return logits, new_state
 
 
-_ARENA_KEYS = ("k_bitmap", "k_values", "v_bitmap", "v_values")
+def _split_arena(state):
+    """``(arena, scanned, layer_ids)`` of a pool state for a layer scan.
+
+    Paged pool: ``arena`` holds each layer's stacked arena leaves, which
+    the scan body closes over (fed to the scan as ``xs``, every layer's
+    arena would be sliced out, and relaid out for the kernel, per step);
+    ``scanned`` the remaining per-slot leaves; ``layer_ids`` the periods'
+    indices, which the body addresses the arena by.  Flat pool: every leaf
+    is per-slot and scanned, ``arena`` empty and ``layer_ids`` None."""
+    layers = state["layers"]
+    if "table" not in state:
+        return {}, layers, None
+    arena = {name: {k: leaf["kv"][k] for k in ARENA_KEYS}
+             for name, leaf in layers.items()}
+    scanned = {name: {"kv": {k: a for k, a in leaf["kv"].items()
+                             if k not in ARENA_KEYS}}
+               for name, leaf in layers.items()}
+    n_periods = next(iter(arena.values()))["k_bitmap"].shape[0]
+    return arena, scanned, jnp.arange(n_periods, dtype=jnp.int32)
+
+
+def _join_arena(arena, layers):
+    """The state's layers again: the scan's per-slot leaves beside the
+    arena leaves, which come back as they went in."""
+    return {name: {"kv": {**arena.get(name, {}), **leaf["kv"]}}
+            for name, leaf in layers.items()}
 
 
 def forward_prefill_chunk(params, state, tokens: jax.Array, slot: jax.Array,
                           cfg, ctx, bs: int,
-                          new_ids: Optional[jax.Array] = None
+                          new_ids: Optional[jax.Array] = None,
+                          caps: Optional[Tuple[int, int]] = None
                           ) -> Tuple[jax.Array, Any]:
     """Prefill one prompt chunk for a single slot of the pooled cache.
 
@@ -515,10 +553,16 @@ def forward_prefill_chunk(params, state, tokens: jax.Array, slot: jax.Array,
 
     Paged pool (``state`` carries a block table): the slot attends to its
     prefix THROUGH its table row (blocks a cache hit pointed at were
-    frozen by other requests), and the chunk's ``C // bs`` new blocks are
-    frozen into FRESH arena pages ``new_ids`` (int32 ``[C // bs]``,
+    frozen by other requests), gathered out of the stacked arena the layer
+    scan closes over, and the chunk's ``C // bs`` new blocks are frozen
+    into FRESH arena pages ``new_ids`` (int32 ``[C // bs]``,
     host-allocated) appended to the table row — never into shared storage,
-    which is the copy-on-write guarantee.
+    which is the copy-on-write guarantee.  Only those pages and the
+    gathered ones are ever reshaped, never the arena.
+
+    ``caps`` is the pool's packed ``(K, V)`` values per block.  A flat
+    pool's leaves hold exactly that many, so it may be left out; the paged
+    arena stores whole padded tiles, so a paged pool must pass it.
     """
     c = tokens.shape[1]
     nb_new, rem = c // bs, c % bs
@@ -533,31 +577,30 @@ def forward_prefill_chunk(params, state, tokens: jax.Array, slot: jax.Array,
         if new_ids is None and nb_new != 0:
             raise ValueError(
                 "paged prefill needs fresh arena ids for its full blocks")
-        # arena leaves are pool-global — only the per-slot tails slice
-        slot_layers = {
-            name: {"kv": {
-                k: (a if k in _ARENA_KEYS
-                    else lax.dynamic_slice_in_dim(a, slot, 1, axis=1))
-                for k, a in leaf["kv"].items()}}
-            for name, leaf in state["layers"].items()}
+        if caps is None:
+            raise ValueError("paged prefill needs the pool's capacities: "
+                             "the arena's tiles are padded")
         sb = state["table"].shape[1]
         table_row = lax.dynamic_slice(
             state["table"], (slot, jnp.int32(0)), (1, sb))[0]
     else:
-        slot_layers = jax.tree_util.tree_map(
-            lambda a: lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
-            state["layers"])
         table_row = None
+    # the arena leaves are pool-global (the body closes over them); only
+    # the per-slot leaves slice
+    arena, scanned, layer_ids = _split_arena(state)
+    slot_layers = jax.tree_util.tree_map(
+        lambda a: lax.dynamic_slice_in_dim(a, slot, 1, axis=1), scanned)
 
     def body(xc, xs):
-        pp, cc = xs
+        pp, cc, layer = xs
         chunk_kv = {}
         for j, kind in enumerate(kinds):
-            pj, cj = pp[f"l{j}"], cc[f"l{j}"]
+            pj, name = pp[f"l{j}"], f"l{j}"
             h = rms_norm(xc, pj["ln1"], cfg.rms_eps)
             h, k_c, v_c = pooled_attn_prefill_chunk(
-                pj["mixer"], h, cj["kv"], cfg, ctx, positions, ctx_len, bs,
-                table_row=table_row)
+                pj["mixer"], h, {**cc[name]["kv"], **arena.get(name, {})},
+                cfg, ctx, positions, ctx_len, bs, table_row=table_row,
+                layer=layer)
             xc = xc + h
             h2 = rms_norm(xc, pj["ln2"], cfg.rms_eps)
             if kind[1] == "moe":
@@ -565,10 +608,11 @@ def forward_prefill_chunk(params, state, tokens: jax.Array, slot: jax.Array,
             else:
                 h2 = mlp_apply(pj["ffn"], h2, ctx)
             xc = xc + h2
-            chunk_kv[f"l{j}"] = {"k": k_c, "v": v_c}
+            chunk_kv[name] = {"k": k_c, "v": v_c}
         return xc, chunk_kv
 
-    x, chunk_kv = lax.scan(body, x, (params["blocks"], slot_layers))
+    x, chunk_kv = lax.scan(body, x,
+                           (params["blocks"], slot_layers, layer_ids))
     hidden = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = logits_fn(params, hidden[:, -1:], cfg, ctx)[:, 0]
 
@@ -581,18 +625,19 @@ def forward_prefill_chunk(params, state, tokens: jax.Array, slot: jax.Array,
         ck, cv = chunk_kv[name]["k"], chunk_kv[name]["v"]    # [P,1,Hkv,C,hd]
         p_, _, hkv, _, hd = ck.shape
         if nb_new:
-            cap_k = kv["k_values"].shape[-1]
-            cap_v = kv["v_values"].shape[-1]
+            cap_k, cap_v = caps or (kv["k_values"].shape[-1],
+                                    kv["v_values"].shape[-1])
             k_bm, k_vl, v_bm, v_vl = freeze_chunk_blocks(
                 ck[:, 0, :, :nb_new * bs], cv[:, 0, :, :nb_new * bs],
                 cfg.kv_k_sparsity, cfg.kv_v_sparsity, bs, cap_k, cap_v)
             for key, upd in (("k_bitmap", k_bm), ("k_values", k_vl),
                              ("v_bitmap", v_bm), ("v_values", v_vl)):
                 if paged:
-                    # [P, Hkv, nb, X] -> [P, nb, Hkv, X] rows into the
-                    # fresh arena pages (never shared storage: CoW)
+                    # [P, Hkv, nb, X] -> [P, nb, Hkv, rows, 128] tiles into
+                    # the fresh arena pages (never shared storage: CoW)
                     kv[key] = kv[key].at[:, new_ids].set(
-                        upd.transpose(0, 2, 1, 3).astype(kv[key].dtype))
+                        to_arena_tiles(upd, kv[key].shape[-2])
+                        .transpose(0, 2, 1, 3, 4).astype(kv[key].dtype))
                 else:
                     kv[key] = lax.dynamic_update_slice(
                         kv[key], upd[:, None].astype(kv[key].dtype),

@@ -28,8 +28,9 @@ just ``k_sparsity = v_sparsity = 0`` (full per-block capacity), for which
 compression is a bit-exact round trip.
 
 **Paged mode** (``paged=True``) generalizes the per-slot block grid into a
-pool-global arena: compressed blocks live once in ``[P, n_phys, Hkv, X]``
-storage, each slot's prefix is a row of the ``[slots, max_blocks]`` int32
+pool-global arena: compressed blocks live once in
+``[P, n_phys, Hkv, rows, 128]`` storage (each block's packed words or
+values as one tile of lane rows, see :func:`arena_rows`), each slot's prefix is a row of the ``[slots, max_blocks]`` int32
 **block table**, and a ``[n_phys]`` **refcount** vector tracks sharing —
 N requests whose prompts share a prefix point their table rows at the SAME
 physical blocks (stored once, attended over once).  Frozen blocks are
@@ -56,7 +57,9 @@ import numpy as np
 from jax.experimental import checkify as _checkify
 
 from repro.core.sparse_format import _ceil_to, LANE
-from repro.core.sparse_kv import append_tail_panel, freeze_chunk_blocks
+from repro.core.sparse_kv import (ARENA_KEYS, append_tail_panel,
+                                  arena_rows, freeze_chunk_blocks,
+                                  to_arena_tiles)
 from repro.models import lm
 
 
@@ -155,8 +158,10 @@ class CachePool:
         Two pools can exchange arena bytes + prefix hashes iff these match:
         block content is a function of the architecture, the block size,
         the packed capacities, and the compression rule; the arena layout
-        adds ``n_phys`` / head-count / dtype.  ``slots`` is deliberately
-        ABSENT — the arena is ``[P, n_phys, Hkv, X]``, slot-independent,
+        adds ``n_phys`` / head-count / dtype and the arena's own layout
+        (``arena_layout``, renamed whenever the stored shape of a block
+        changes).  ``slots`` is deliberately ABSENT — the arena is
+        ``[P, n_phys, Hkv, rows, 128]``, slot-independent,
         so a restarted server may resize its slot count and still restore
         a warm cache.  (Content-addressed chained hashes make *stale* data
         impossible by construction; geometry is the only thing to check.)
@@ -176,6 +181,7 @@ class CachePool:
             "cdtype": np.dtype(cfg.cdtype).name,
             "kv_k_sparsity": cfg.kv_k_sparsity,
             "kv_v_sparsity": cfg.kv_v_sparsity,
+            "arena_layout": ARENA_LAYOUT if self.paged else None,
         }
 
     # -- sanitized mode -----------------------------------------------------
@@ -195,7 +201,9 @@ class CachePool:
 
         Flat pool: compressed leaves are per-slot grids
         ``[P, slots, Hkv, max_blocks, X]``.  Paged pool: compressed leaves
-        are the shared arena ``[P, n_phys, Hkv, X]`` plus the pool-level
+        are the shared arena ``[P, n_phys, Hkv, rows, 128]`` (a block's
+        ``X`` packed entries as ``rows`` lane rows, :func:`arena_rows`,
+        the padding zero) plus the pool-level
         ``table [slots, max_blocks]`` / ``refcount [n_phys]`` int32
         vectors; the dense tails stay per-slot either way (the tail is
         private working state, never shared)."""
@@ -207,15 +215,13 @@ class CachePool:
 
         def kv_leaf():
             if self.paged:
-                n = self.n_phys
-                comp = {
-                    "k_bitmap": jnp.zeros((n_periods, n, hkv, w), jnp.uint32),
-                    "k_values": jnp.zeros((n_periods, n, hkv, self.cap_k),
-                                          dt),
-                    "v_bitmap": jnp.zeros((n_periods, n, hkv, w), jnp.uint32),
-                    "v_values": jnp.zeros((n_periods, n, hkv, self.cap_v),
-                                          dt),
-                }
+                tile = lambda x, dtype: jnp.zeros(  # noqa: E731
+                    (n_periods, self.n_phys, hkv, arena_rows(x), LANE),
+                    dtype)
+                comp = {"k_bitmap": tile(w, jnp.uint32),
+                        "k_values": tile(self.cap_k, dt),
+                        "v_bitmap": tile(w, jnp.uint32),
+                        "v_values": tile(self.cap_v, dt)}
             else:
                 comp = {
                     "k_bitmap": jnp.zeros((n_periods, b, hkv, sb, w),
@@ -265,7 +271,7 @@ class CachePool:
         p = lm.period_len(self.cfg)
 
         def kv_axes():
-            comp = ((None, None, "kv_heads", None) if self.paged
+            comp = ((None, None, "kv_heads", None, None) if self.paged
                     else (None, "slots", "kv_heads", None, None))
             tail = (None, "slots", "kv_heads", None, None)
             return {**{k: comp for k in ("k_bitmap", "k_values",
@@ -348,10 +354,11 @@ class CachePool:
             if self.paged:
                 # an id past the arena is dropped by every scatter below
                 slot_ids = ids[s]                               # [tb]
-                # [P, Hkv, tb, X] -> [P, tb, Hkv, X] rows at the fresh ids
-                # on the arena's physical-block axis
+                # [P, Hkv, tb, X] -> [P, tb, Hkv, rows, 128] tiles at the
+                # fresh ids on the arena's physical-block axis
                 write = lambda dst, upd: dst.at[:, slot_ids].set(
-                    upd.transpose(0, 2, 1, 3).astype(dst.dtype), mode="drop")
+                    to_arena_tiles(upd, dst.shape[-2])
+                    .transpose(0, 2, 1, 3, 4).astype(dst.dtype), mode="drop")
             else:
                 write = lambda dst, upd: jax.lax.dynamic_update_slice(
                     dst, upd[:, None].astype(dst.dtype), (0, s, 0, off, 0))
@@ -567,9 +574,9 @@ class CachePool:
         return {**state, "layers": new_layers}
 
 
-# the compressed-block storage leaves of one layer's kv tree — the paged
-# arena's persistent content (tails are private in-flight state)
-ARENA_KEYS = ("k_bitmap", "k_values", "v_bitmap", "v_values")
+# the paged arena's stored layout, part of a snapshot's geometry: a block
+# is a [rows, 128] tile, the rows a multiple of 8, under [P, n_phys, Hkv]
+ARENA_LAYOUT = "period-stacked-tiles-8"
 
 
 # errors screened by the sanitized mode: the pool's own checkify.check

@@ -488,8 +488,9 @@ class ContinuousEngine:
             return tok, logp, {**st, "sample": lanes}
 
         def _prefill(p, st, t, s, final, ids=None):
-            logits, st = lm.forward_prefill_chunk(p, st, t, s, cfg, ctx, bs_,
-                                                  new_ids=ids)
+            logits, st = lm.forward_prefill_chunk(
+                p, st, t, s, cfg, ctx, bs_, new_ids=ids,
+                caps=(self.pool.cap_k, self.pool.cap_v))
             lanes = st["sample"]
             lane = {k: jax.lax.dynamic_slice_in_dim(v, s, 1, axis=0)
                     for k, v in lanes.items()}

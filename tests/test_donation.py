@@ -14,6 +14,7 @@ import dataclasses
 import json
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -94,6 +95,35 @@ def test_donation_changes_no_token(setup, paged, overlap, spec):
     prompts = _prompts(cfg)
     want = _serve(_engine(params, cfg, donate=False, **kw), prompts)
     assert _serve(_engine(params, cfg, donate=True, **kw), prompts) == want
+
+
+@pytest.mark.parametrize("entry", ["decode", "decode_chain"])
+def test_decode_step_hands_the_arena_back_in_place(setup, entry):
+    """Paged and donated: a decode step returns every arena leaf in the
+    buffer it was given, with the bytes unchanged -- the step reads the
+    stacked arena where it lies and never writes it."""
+    from repro.serving import ARENA_KEYS
+    cfg, params = setup
+    eng = _engine(params, cfg, paged=True, overlap=True, donate=True)
+    eng.submit(_prompts(cfg, lens=(40,))[0], SamplingParams(max_new_tokens=4))
+    eng.run()                           # frozen pages in the arena
+    arena = lambda st: [st["layers"][name]["kv"][k]  # noqa: E731
+                        for name in st["layers"] for k in ARENA_KEYS]
+    ptrs = [a.unsafe_buffer_pointer() for a in arena(eng.state)]
+    # copies: a host view of a buffer would keep it from being donated
+    want = [np.array(a, copy=True) for a in arena(eng.state)]
+    b = eng.pool.slots
+    mask = jnp.ones((b,), bool)
+    if entry == "decode":
+        _, _, st = eng._decode(eng.params, eng.state,
+                               jnp.ones((b, 1), jnp.int32), mask)
+    else:
+        zeros = jnp.zeros((b,), jnp.int32)
+        _, _, st = eng._decode_chain(eng.params, eng.state, zeros, zeros,
+                                     jnp.zeros((b,), bool), mask)
+    assert [a.unsafe_buffer_pointer() for a in arena(st)] == ptrs
+    for got, w in zip(arena(st), want):
+        np.testing.assert_array_equal(np.asarray(got), w)
 
 
 def test_donation_under_checkify(setup):

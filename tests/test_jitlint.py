@@ -96,6 +96,28 @@ def test_audit_accepts_clipped_arena_gather():
     assert findings == [], findings
 
 
+def test_audit_checks_the_stacked_arena_gather():
+    """The paged arena is stored ``[P, n_phys, ...]``: a gather of
+    ``(layer, table entry)`` out of it is arena access too, flagged in
+    PROMISE_IN_BOUNDS mode and accepted clipped."""
+    n_phys = 29
+    arena = _abs((2, n_phys, 4, 8, 128), jnp.float32)
+    ids = (_abs((), jnp.int32), _abs((3,), jnp.int32))
+
+    def bad(arena, layer, table_row):
+        return arena.at[layer, table_row].get(mode="promise_in_bounds")
+
+    def good(arena, layer, table_row):
+        return arena.at[layer, table_row].get(mode="clip")
+
+    findings, _ = audit_jaxpr(jax.make_jaxpr(bad)(arena, *ids), "bad", GEO,
+                              n_phys=n_phys)
+    assert any(f.rule == "table-gather-bounds" for f in findings), findings
+    findings, _ = audit_jaxpr(jax.make_jaxpr(good)(arena, *ids), "good",
+                              GEO, n_phys=n_phys)
+    assert findings == [], findings
+
+
 def test_audit_ignores_non_arena_gather():
     """PROMISE_IN_BOUNDS over a non-arena-shaped operand is fine — the
     rule keys on the leading dim matching n_phys."""

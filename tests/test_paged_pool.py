@@ -51,7 +51,8 @@ except ImportError:                      # container ships without hypothesis
         return deco
 
 from repro.configs import get_config
-from repro.core.sparse_kv import freeze_chunk_blocks
+from repro.core.sparse_kv import (arena_rows, freeze_chunk_blocks,
+                                  to_arena_tiles)
 from repro.kernels import ops
 from repro.models import lm
 from repro.serving import (BlockAllocator, CachePool, ContinuousEngine,
@@ -68,13 +69,22 @@ def _rand(shape, seed):
 # kernel: table indirection vs the gather-then-flat oracle
 # ---------------------------------------------------------------------------
 
-def _arena_case(n_phys=10, hkv=2, bs=16, d=32, ks=0.3, vs=0.5, seed=0):
-    """A frozen arena of ``n_phys`` independent compressed blocks."""
-    k = _rand((n_phys, hkv, bs, d), seed)
-    v = _rand((n_phys, hkv, bs, d), seed + 1)
-    cap = bs * d
-    kbm, kvl, vbm, vvl = freeze_chunk_blocks(k, v, ks, vs, bs, cap, cap)
-    return tuple(a[:, :, 0] for a in (kbm, kvl, vbm, vvl))  # [n_phys,Hkv,X]
+def _arena_case(n_phys=10, hkv=2, bs=16, d=32, ks=0.3, vs=0.5, seed=0,
+                n_periods=3, layer=1):
+    """A frozen arena of ``n_phys`` independent compressed blocks in
+    period ``layer`` of a pool-layout ``[P, n_phys, Hkv, rows, 128]``
+    arena, whose other periods hold other blocks (the kernel must read the
+    layer it is given)."""
+    def period(seed):
+        k = _rand((n_phys, hkv, bs, d), seed)
+        v = _rand((n_phys, hkv, bs, d), seed + 1)
+        cap = bs * d
+        leaves = freeze_chunk_blocks(k, v, ks, vs, bs, cap, cap)
+        return [to_arena_tiles(a[:, :, 0], arena_rows(a.shape[-1]))
+                for a in leaves]                    # [n_phys,Hkv,rows,128]
+    periods = [period(seed if p == layer else seed + 100 * (p + 1))
+               for p in range(n_periods)]
+    return tuple(jnp.stack(ls) for ls in zip(*periods))
 
 
 # tables share physical pages across slots on purpose — that sharing is
@@ -111,12 +121,13 @@ def test_paged_kernel_matches_gather_oracle(table, prefix_blocks, tail_len,
     if qn:                          # panel query j sees tail_len + j
         tl = jnp.minimum(tl, t - (qn - 1))
     sm = 1.0 / d ** 0.5
+    layer = jnp.int32(1)
     with ops.backend("xla"):
         o_ref = ops.sparse_decode_attention_paged(
-            q, *arena, tbl, hkv, sm, bs, k_tail, v_tail, tl, pl_)
+            q, *arena, tbl, layer, hkv, sm, bs, k_tail, v_tail, tl, pl_)
     with ops.backend("interpret"):
         o_k = ops.sparse_decode_attention_paged(
-            q, *arena, tbl, hkv, sm, bs, k_tail, v_tail, tl, pl_)
+            q, *arena, tbl, layer, hkv, sm, bs, k_tail, v_tail, tl, pl_)
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-4)
 
@@ -137,8 +148,11 @@ def test_poisoned_arena_blocks_never_read(backend):
             for i in range(prefix_blocks[s])}
     dead = np.asarray([p not in live for p in range(n_phys)])
     assert dead.any(), "case must exercise dead pages"
+    # dead pages of the attended period, and every page of the others
+    dead_pages = np.ones((3, n_phys), bool)
+    dead_pages[1] = dead
     poisoned = tuple(
-        jnp.where(dead[:, None, None],
+        jnp.where(dead_pages[:, :, None, None, None],
                   jnp.full(a.shape, ~np.uint32(0))
                   if a.dtype == jnp.uint32 else jnp.full(a.shape, 1e4),
                   a).astype(a.dtype)
@@ -149,11 +163,13 @@ def test_poisoned_arena_blocks_never_read(backend):
     v_tail = _rand((b, hkv, t, d), 21)
     q = _rand((b, hkv * g, d), 22)
     sm = 1.0 / d ** 0.5
+    layer = jnp.int32(1)
     with ops.backend(backend):
         o_clean = ops.sparse_decode_attention_paged(
-            q, *arena, table, hkv, sm, bs, k_tail, v_tail, tl, pl_)
+            q, *arena, table, layer, hkv, sm, bs, k_tail, v_tail, tl, pl_)
         o_poison = ops.sparse_decode_attention_paged(
-            q, *poisoned, table, hkv, sm, bs, k_tail, v_tail, tl, pl_)
+            q, *poisoned, table, layer, hkv, sm, bs, k_tail, v_tail, tl,
+            pl_)
     np.testing.assert_array_equal(np.asarray(o_clean), np.asarray(o_poison))
 
 
@@ -466,6 +482,7 @@ def _all_rows_refreeze(pool, state, new_ids=None):
             def write(dst, upd):
                 u = upd.reshape(p_, b_, hkv, tb, -1)
                 u = u.transpose(0, 1, 3, 2, 4).reshape(p_, b_ * tb, hkv, -1)
+                u = to_arena_tiles(u, dst.shape[-2])     # the arena's tiles
                 return dst.at[:, drop_ids].set(u.astype(dst.dtype),
                                                mode="drop")
         else:
@@ -852,3 +869,60 @@ def test_paged_interpret_mode_parity():
         out_paged = [eng.run()[r].token_ids for r in rp]
         assert eng.trace_counts()["decode"] == 1
     assert out_paged == out_flat
+
+
+def test_paged_interpret_spec_fold_and_hit_parity():
+    """Through the Pallas kernels (interpret mode), the paged engine reads
+    its stacked arena in place on every path a request takes: a
+    speculative verify panel (``Qn = K + 1``), decode past a fold of the
+    tail ring, and a prefix-cache hit of a second request.  Its greedy
+    tokens are the flat engine's on the same backend."""
+    cfg, params = _setup(kv_tail=16)
+    rng = np.random.default_rng(5)
+    shared = rng.integers(0, cfg.vocab, (32,)).tolist()
+    prompts = [shared + [7, 8, 9] * 2,
+               shared + rng.integers(0, cfg.vocab, (3,)).tolist()]
+    sp = SamplingParams(max_new_tokens=14)    # 38 + 14 > 48: one fold
+    def one_by_one(eng):
+        out = []
+        for p in prompts:
+            rid = eng.submit(p, sp)
+            out.append(eng.run()[rid].token_ids)
+        return out
+    with ops.backend("interpret"):
+        out_flat = one_by_one(ContinuousEngine(
+            params, cfg, slots=1, max_tokens=64, bs=16, prefill_chunk=32))
+        eng = ContinuousEngine(params, cfg, slots=1, max_tokens=64, bs=16,
+                               prefill_chunk=32, paged=True,
+                               spec=SpecConfig(k=2))
+        out_paged = one_by_one(eng)
+        counts = eng.trace_counts()
+    assert out_paged == out_flat
+    assert counts["verify"] == 1 and counts["refreeze"] == 1
+    assert counts["assign"] == 1              # the second prompt's hit
+
+
+def test_snapshot_of_another_arena_layout_is_refused(tmp_path):
+    """The arena's stored layout is part of a snapshot's geometry: pages
+    saved in another layout are refused by name, never loaded."""
+    import json
+    import os
+    cfg, params = _setup()
+    eng = ContinuousEngine(params, cfg, slots=2, max_tokens=64, bs=16,
+                           prefill_chunk=32, paged=True)
+    assert eng.pool.geometry()["arena_layout"]
+    eng.submit(list(range(40)), SamplingParams(max_new_tokens=2))
+    eng.run()
+    snap = str(tmp_path / "snap")
+    step = eng.save_snapshot(snap)
+    man = os.path.join(snap, f"step_{step:010d}", "manifest.json")
+    with open(man) as f:
+        manifest = json.load(f)
+    del manifest["geometry"]["arena_layout"]   # a snapshot from before it
+    with open(man, "w") as f:
+        json.dump(manifest, f)
+    fresh = ContinuousEngine(params, cfg, slots=2, max_tokens=64, bs=16,
+                             prefill_chunk=32, paged=True)
+    with pytest.raises(ValueError, match="arena_layout"):
+        fresh.load_snapshot(snap)
+    assert len(fresh._trie) == 0

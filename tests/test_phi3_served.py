@@ -110,3 +110,26 @@ def test_head_pad_rows_are_never_sampled(served):
         toks = out[r].token_ids
         assert len(toks) == 24 and max(toks) < cfg.vocab
         assert np.all(np.isfinite(out[r].logprobs))
+
+
+def test_arena_holds_whole_tiles_and_zero_padding(served):
+    """head_dim 96: a block's bitmap words and packed values fill rows of
+    128 lanes that are neither whole nor a multiple of 8.  The paged
+    arena keeps each block as one ``[rows, 128]`` tile, the rows rounded
+    up to a multiple of 8, under ``[P, n_phys, Hkv]``; what prefill and
+    the folds wrote past a block's own entries is zero."""
+    from repro.core.sparse_kv import arena_rows
+    _, cfg, eng, _, _ = served
+    pool = eng.pool
+    words = pool.bs * cfg.hd // 32
+    assert cfg.hd == 96 and words % 128
+    for leaf in eng.state["layers"].values():
+        for key, x in (("k_bitmap", words), ("k_values", pool.cap_k),
+                       ("v_bitmap", words), ("v_values", pool.cap_v)):
+            a = np.asarray(leaf["kv"][key])
+            assert a.shape == (cfg.n_layers, pool.n_phys, cfg.n_kv,
+                               arena_rows(x), 128)
+            assert a.shape[3] % 8 == 0 and a.shape[3] * 128 >= x
+            flat = a.reshape(*a.shape[:3], -1)
+            assert flat[..., :x].any(), key      # pages were written
+            assert not flat[..., x:].any(), key
